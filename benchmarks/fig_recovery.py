@@ -11,7 +11,9 @@ Two halves, results in ``BENCH_recovery.json``:
    dataplane would spin until ``max_rounds`` and raise).
 
 2. Training-side: the elastic controller (runtime/controller.py) in a
-   subprocess with 8 host devices, one host killed mid-run. Measures
+   subprocess with 8 virtual CPU devices, one host killed mid-run. The child
+   is pinned to the CPU backend (the parent already holds any accelerator),
+   so its timings are labelled ``platform: cpu``. Measures
    steps-to-detect (heartbeat timeout), steps replayed (checkpoint cadence),
    wall-clock recovery overhead vs the uninterrupted run, and post-failure
    goodput (tok/s on the survivor mesh vs before the kill) — while asserting
@@ -106,6 +108,7 @@ def _train_half() -> dict:
     kill_at = steps // 2
     code = _TRAIN_CODE.format(steps=steps, kill_at=kill_at)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
@@ -123,6 +126,7 @@ def _train_half() -> dict:
     post = [e for e in faulted if e["mesh"] < W][1:]  # [0] is the re-jit step
     pre = [e for e in faulted if e["mesh"] == W][1:kill_at]
     out = {
+        "platform": "cpu",
         "steps": steps,
         "kill_at": kill_at,
         "steps_to_detect": rec["steps_to_detect"],
@@ -143,7 +147,7 @@ def _train_half() -> dict:
          f"detect={out['steps_to_detect']};replay={out['steps_replayed']}")
     emit("recovery.post_failure_tok_s", 0,
          f"tok_s={out['post_failure_tok_s']:.0f};"
-         f"pre={out['pre_failure_tok_s']:.0f}")
+         f"pre={out['pre_failure_tok_s']:.0f};platform=cpu")
     return out
 
 

@@ -1,11 +1,15 @@
 """Benchmark runner — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (see each module's docstring for
-what it reproduces and the paper's claim it is checked against).
+what it reproduces and the paper's claim it is checked against). A module
+that raises prints an ``ERROR`` row, the rest still run, and the exit code is
+non-zero.
 """
 import sys
 import traceback
 from time import perf_counter
+
+from repro.launch.compile_cache import use_compile_cache
 
 MODULES = [
     "tab1_alu_cost",
@@ -24,9 +28,11 @@ MODULES = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    use_compile_cache()
     print("name,us_per_call,derived")
     only = sys.argv[1:] or None
+    failed = []
     for name in MODULES:
         if only and name not in only:
             continue
@@ -38,7 +44,11 @@ def main() -> None:
         except Exception as e:  # noqa: BLE001 — report, keep going
             traceback.print_exc()
             print(f"{name}.wall,{(perf_counter()-t0)*1e6:.0f},ERROR:{type(e).__name__}")
+            failed.append(name)
+    if failed:
+        print(f"FAILED: {' '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -44,8 +44,6 @@ def run():
     for name, fn, args in rows:
         dt, _ = timeit(fn, *args)
         ca = jax.jit(fn).lower(*args).compile().cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # old jax returns [dict]
-            ca = ca[0] if ca else {}
         flops = ca.get("flops", 0)
         emit(name, dt * 1e6, f"x_native={dt/t_add:.2f};ops_per_elem={flops/n:.1f}")
     # paper's silicon numbers for context (um^2 at 15nm, Tab. 1)

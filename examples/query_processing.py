@@ -9,9 +9,11 @@ import time
 import numpy as np
 
 from repro.db import query as q
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     rng = np.random.default_rng(1)
     rows = 100_000
     ad_revenue = rng.gamma(2.0, 50.0, rows).astype(np.float32)

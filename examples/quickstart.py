@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from repro.core import fpisa as F
 from repro.core import numerics as nx
 from repro.core.agg import add_agg_args, resolve_backend
-from repro.kernels import fpisa_fused
+from repro.kernels import ops
+from repro.launch.compile_cache import use_compile_cache
 from repro.trace import add_trace_args
 from repro.trace import from_args as trace_from_args
 
@@ -33,6 +34,7 @@ add_agg_args(ap)  # the same shared --agg-* flags every entry point uses
 add_trace_args(ap)  # the shared --trace-* flags (repro.trace)
 ap.set_defaults(bucket_bytes=1 << 16)  # step 4's whole-pytree demo
 args = ap.parse_args()
+use_compile_cache()
 backend = resolve_backend(args.agg_backend)
 session = trace_from_args(args)  # spans from step 4's Aggregator calls
 
@@ -67,16 +69,13 @@ def block_aggregate(chunk: np.ndarray) -> jnp.ndarray:
         # fused single-pass kernels (interpret mode off-TPU), local block max
         # + exact residual shift to the cross-worker max — bit-identical to
         # the jnp formulation (shift composition, see kernels/README.md)
-        interp = jax.default_backend() != "tpu"
-        mans, bmaxs = zip(*(fpisa_fused.fused_encode_align(
-            jnp.asarray(chunk[w]).reshape(-1, BLOCK),
-            interpret=interp) for w in range(W)))
+        mans, bmaxs = zip(*(ops.encode_align(
+            jnp.asarray(chunk[w]).reshape(-1, BLOCK)) for w in range(W)))
         bmax = jnp.max(jnp.stack(bmaxs), axis=0)
         man = jnp.stack([
             nx.arshift(m, (bmax - bm)[:, None] + s) for m, bm in zip(mans, bmaxs)])
         man_sum = man.sum(0)
-        return fpisa_fused.fused_decode(
-            man_sum, bmax, preshift=s, interpret=interp).reshape(-1)
+        return ops.decode_fused(man_sum, bmax, preshift=s).reshape(-1)
     p = F.encode(jnp.asarray(chunk).reshape(-1))
     pe = p.exp.reshape(W, chunk.shape[1])
     bmax = jnp.max(F.block_max_exponent(pe, BLOCK), axis=0)  # "pmax across workers"

@@ -16,6 +16,7 @@ import jax
 
 from repro.configs import get_smoke_config
 from repro.core.agg import AggConfig, add_agg_args
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build, param_count
 from repro.serve.engine import ServeEngine
 from repro.serve.loadgen import PoissonLoadGen, latency_report
@@ -38,6 +39,7 @@ def main():
                     help="Poisson arrival rate, requests per scheduler step")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     try:
         agg = AggConfig.from_args(args)
     except ValueError as e:

@@ -10,6 +10,7 @@ import argparse
 
 from repro.configs import get_config
 from repro.core.agg import AggConfig, add_agg_args
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import train_loop
 from repro.trace import add_trace_args
 from repro.trace import from_args as trace_from_args
@@ -38,6 +39,7 @@ def main():
                     help="logical worker count for the controller path "
                          "(default: one per device)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.smoke:
         from repro.configs import get_smoke_config

@@ -1,65 +1,30 @@
-"""Version-compatibility shims for the jax API surface this repo targets.
+"""One spelling for the mesh and shard_map calls used across the repo.
 
-The code is written against the modern jax API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``). Older jax
-releases (e.g. the 0.4.x baked into the CPU test container) expose shard_map
-only under ``jax.experimental.shard_map`` with (check_rep, auto) instead of
-(check_vma, axis_names), and have no AxisType at all. Importing ``make_mesh``
-and ``shard_map`` from here gives every caller — src, tests, examples — one
-spelling that works on both.
+``make_mesh`` and ``abstract_mesh`` give every axis the Auto type (jax's own
+``make_mesh`` defaults to Explicit); ``shard_map`` defaults ``check_vma`` to
+False and makes every mesh axis manual unless ``axis_names`` says otherwise.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    AXIS_TYPE_AUTO = jax.sharding.AxisType.Auto
-except AttributeError:  # older jax: meshes have no axis types
-    AXIS_TYPE_AUTO = None
-
-
-def axis_size(name) -> int:
-    """Static size of a named (manual) mesh axis, on any jax version."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    frame = jax.core.axis_frame(name)  # old jax: returns the size itself
-    return frame if isinstance(frame, int) else frame.size
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_mesh(axis_shapes, axis_names, **kwargs):
-    """jax.make_mesh with every axis Auto, on any jax version."""
-    if AXIS_TYPE_AUTO is not None:
-        kwargs.setdefault("axis_types", (AXIS_TYPE_AUTO,) * len(axis_names))
+    """jax.make_mesh with every axis Auto."""
+    kwargs.setdefault("axis_types", (AxisType.Auto,) * len(axis_names))
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
 def abstract_mesh(axis_shapes, axis_names):
-    """jax.sharding.AbstractMesh with every axis Auto, on any jax version."""
-    from jax.sharding import AbstractMesh
-
-    if AXIS_TYPE_AUTO is not None:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names),
-                            axis_types=(AXIS_TYPE_AUTO,) * len(axis_names))
-    return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    """jax.sharding.AbstractMesh with every axis Auto."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=False):
-    """jax.shard_map adapter.
-
-    ``axis_names``: the MANUAL axes (modern spelling); every other mesh axis
-    stays auto. On old jax this maps to ``auto = mesh.axis_names - axis_names``
-    and ``check_vma`` maps to ``check_rep``.
-    """
+    """jax.shard_map; ``axis_names`` are the MANUAL axes (default: all of
+    them), every other mesh axis stays auto."""
     manual = set(axis_names) if axis_names is not None else set(mesh.axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=manual, check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(a for a in mesh.axis_names if a not in manual)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto,
-    )
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=manual, check_vma=check_vma)

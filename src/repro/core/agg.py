@@ -56,6 +56,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import layout
 
 from repro import trace as _trace
 
@@ -533,10 +534,15 @@ class Aggregator:
         (core/bucketer.py) — bit-identical to the per-leaf path but with the
         per-collective encode/decode overhead amortized over whole buckets.
         Otherwise: per-leaf tree_map (XLA's latency-hiding scheduler still
-        overlaps the independent per-leaf collectives with other work)."""
+        overlaps the independent per-leaf collectives with other work).
+
+        The tree goes in and comes out through an optimization barrier, in
+        row-major layout (``_row_major``), so the code around the aggregation
+        compiles the same whichever backend runs inside it."""
         with _trace.span("agg.allreduce_tree", strategy=self.spec.name,
                          backend=self.backend, stacked=self.stacked,
                          bucket_bytes=self.cfg.bucket_bytes) as sp:
+            tree = lax.optimization_barrier(_row_major(tree))
             if self.cfg.bucket_bytes:
                 from repro.core import bucketer
 
@@ -548,5 +554,21 @@ class Aggregator:
                         tree, self.axes, self.cfg)
             else:
                 out = jax.tree_util.tree_map(self.allreduce, tree)
+            out = _row_major(lax.optimization_barrier(out))
             sp.sync(out)
         return out
+
+
+def _row_major(tree):
+    """Pin every leaf of ``tree`` to the row-major layout.
+
+    Together with the optimization barriers in ``Aggregator.allreduce_tree``
+    this makes the aggregation a fixed boundary. Without it, XLA on TPU fuses
+    the jnp backend's elementwise encode/decode into the code around it and
+    lets the backward matmuls' layouts flow through to the optimizer, while
+    a Pallas kernel's operands and results are row-major: the optimizer's
+    gradient-norm reduction then sums the same values in another order, and
+    the two backends' trajectories part by an ulp."""
+    return jax.tree_util.tree_map(
+        lambda x: layout.with_layout_constraint(
+            x, layout.Layout(major_to_minor=tuple(range(x.ndim)))), tree)

@@ -90,7 +90,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core import agg as _agg
 from repro.core.agg import (  # noqa: F401  (re-exported legacy surface)
     AggConfig, BACKENDS, DEFAULT_BLOCK, register_strategy, resolve_backend,
@@ -98,12 +97,7 @@ from repro.core.agg import (  # noqa: F401  (re-exported legacy surface)
 from repro.core import fpisa
 from repro.core import numerics as nx
 from repro.kernels import fpisa_fused
-
-
-def _interpret() -> bool:
-    # On non-TPU hosts the Pallas kernels run under the interpreter (bit-exact
-    # same semantics) so the TPU code path is testable everywhere.
-    return jax.default_backend() != "tpu"
+from repro.kernels.ops import interpret as _interpret
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +142,7 @@ def _decode(man_sum: jax.Array, bmax: jax.Array, shift: int, cfg: AggConfig,
 
 
 def _axis_size(axis_names: Sequence[str]) -> int:
-    return math.prod(compat.axis_size(a) for a in axis_names)
+    return math.prod(lax.axis_size(a) for a in axis_names)
 
 
 def _flatten_pad(x: jax.Array, block: int):
@@ -313,8 +307,8 @@ def _hier_collect(man: jax.Array, data_axis: str, pod_axis: str,
     overlap this phase with the encode of the next bucket.
     """
     fmt = cfg.fmt
-    w_data = compat.axis_size(data_axis)
-    w_pod = compat.axis_size(pod_axis)
+    w_data = lax.axis_size(data_axis)
+    w_pod = lax.axis_size(pod_axis)
     # level 1: in-pod reduce-scatter (int32 wire on ICI)
     man_shard = lax.psum_scatter(man, data_axis, scatter_dimension=0, tiled=True)
     # level 2: cross-pod integer psum, optionally narrow wire. The in-pod
@@ -341,7 +335,7 @@ def _hier_collect(man: jax.Array, data_axis: str, pod_axis: str,
 def _hier_finish(man_shard: jax.Array, bmax: jax.Array, shift: int,
                  pod_shift: int, data_axis: str, cfg: AggConfig, backend: str):
     """Delayed renorm on the owned shard only, then gather packed FP."""
-    w_data = compat.axis_size(data_axis)
+    w_data = lax.axis_size(data_axis)
     idx = lax.axis_index(data_axis)
     blocks_per_shard = bmax.shape[0] // w_data
     bmax_shard = lax.dynamic_slice_in_dim(bmax, idx * blocks_per_shard, blocks_per_shard)
@@ -364,8 +358,8 @@ def fpisa_allreduce_hierarchical(
     compatible across levels; the sum stays in integer domain end-to-end and
     renormalization happens ONCE (delayed, as in the paper).
     """
-    w_data = compat.axis_size(data_axis)
-    w_pod = compat.axis_size(pod_axis)
+    w_data = lax.axis_size(data_axis)
+    w_pod = lax.axis_size(pod_axis)
     w = w_data * w_pod
     fmt = cfg.fmt
     backend = resolve_backend(cfg.backend)
@@ -647,8 +641,8 @@ def _fpisa_hier_phases(data_axis, pod_axis, cfg: AggConfig, backend: str,
     pod axis's uplinks. Rolling by whole shards keeps every block's contents
     intact, so the result is bit-identical to the unstriped path.
     """
-    w_data = compat.axis_size(data_axis)
-    w_pod = compat.axis_size(pod_axis)
+    w_data = lax.axis_size(data_axis)
+    w_pod = lax.axis_size(pod_axis)
     shift = _wire_shift(cfg.fmt, w_data * w_pod, cfg.wire_bits)
     quantum = cfg.block * w_data
 

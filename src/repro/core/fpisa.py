@@ -44,9 +44,15 @@ __all__ = [
     "FORMATS",
     "FpFormat",
     "Planes",
+    "PACKED_DTYPE",
+    "BITS_DTYPE",
+    "to_bits",
+    "from_bits",
     "encode",
+    "encode_bits",
     "decode",
     "renormalize",
+    "renormalize_bits",
     "fpisa_add_full",
     "fpisa_a_add",
     "fpisa_sum_sequential",
@@ -67,24 +73,23 @@ class Planes(NamedTuple):
 # Packed-bits extraction per format
 # ---------------------------------------------------------------------------
 
-_PACKED_DTYPE = {"fp32": jnp.float32, "fp16": jnp.float16, "bf16": jnp.bfloat16}
-_BITS_DTYPE = {"fp32": jnp.int32, "fp16": jnp.int16, "bf16": jnp.int16}
+PACKED_DTYPE = {"fp32": jnp.float32, "fp16": jnp.float16, "bf16": jnp.bfloat16}
+BITS_DTYPE = {"fp32": jnp.int32, "fp16": jnp.int16, "bf16": jnp.int16}
 
 
-def _to_bits(x: jax.Array, fmt: FpFormat) -> jax.Array:
-    """Bitcast packed FP values to an int32 tensor holding the raw bits."""
-    packed = jnp.asarray(x, _PACKED_DTYPE[fmt.name])
-    bits = packed.view(_BITS_DTYPE[fmt.name])
-    if fmt.name != "fp32":
-        bits = bits.astype(jnp.int32) & 0xFFFF
-    return bits.astype(jnp.int32)
+def to_bits(x: jax.Array, fmt: FpFormat) -> jax.Array:
+    """Convert to the packed format, then bitcast to the same-width signed int
+    (int32 for fp32, int16 for the 16-bit formats).
+
+    The Pallas kernels take and return these integer bits and do the float
+    bitcast outside the kernel, because Mosaic on v5e cannot load or cast
+    f16 vectors while int16/int32 tiles work."""
+    return jnp.asarray(x, PACKED_DTYPE[fmt.name]).view(BITS_DTYPE[fmt.name])
 
 
-def _from_bits(bits: jax.Array, fmt: FpFormat) -> jax.Array:
-    if fmt.name == "fp32":
-        return bits.astype(jnp.int32).view(jnp.float32)
-    b16 = bits.astype(jnp.uint16).view(jnp.int16)
-    return b16.view(_PACKED_DTYPE[fmt.name])
+def from_bits(bits: jax.Array, fmt: FpFormat) -> jax.Array:
+    """Raw bits (any int dtype; the low ``total_bits`` are kept) -> packed FP."""
+    return bits.astype(BITS_DTYPE[fmt.name]).view(PACKED_DTYPE[fmt.name])
 
 
 def encode(x: jax.Array, fmt: FpFormat = FP32) -> Planes:
@@ -96,7 +101,13 @@ def encode(x: jax.Array, fmt: FpFormat = FP32) -> Planes:
     finite value of the format (documented deviation — the paper assumes
     finite inputs).
     """
-    bits = _to_bits(x, fmt)
+    return encode_bits(to_bits(x, fmt), fmt)
+
+
+def encode_bits(bits: jax.Array, fmt: FpFormat = FP32) -> Planes:
+    """``encode`` on the raw bits that ``to_bits`` gives. Only the low
+    ``total_bits`` are read, so a sign-extending widening is harmless."""
+    bits = bits.astype(jnp.int32)
     total = fmt.total_bits
     sign = (bits >> (total - 1)) & 1
     exp = (bits >> fmt.man_bits) & fmt.exp_mask
@@ -121,6 +132,11 @@ def renormalize(planes: Planes, fmt: FpFormat = FP32) -> jax.Array:
     round-toward-negative-infinity (Appendix A.1); exponent overflow clamps to
     +/-inf; underflow flushes to zero.
     """
+    return from_bits(renormalize_bits(planes, fmt), fmt)
+
+
+def renormalize_bits(planes: Planes, fmt: FpFormat = FP32) -> jax.Array:
+    """``renormalize`` returning the packed value's raw bits as int32."""
     e, m = jnp.asarray(planes.exp, jnp.int32), jnp.asarray(planes.man, jnp.int32)
     neg = m < 0
     mag = jnp.abs(m).astype(jnp.uint32)
@@ -155,8 +171,7 @@ def renormalize(planes: Planes, fmt: FpFormat = FP32) -> jax.Array:
         | man_out
     )
     # zero: keep signless +0 (switch register cannot hold -0 distinctly)
-    bits = jnp.where(zero, 0, bits)
-    return _from_bits(bits, fmt)
+    return jnp.where(zero, 0, bits)
 
 
 def decode(planes: Planes, fmt: FpFormat = FP32) -> jax.Array:

@@ -27,14 +27,14 @@ def _accum_kernel(x_ref, out_ref, *, num_workers: int, variant: str, fmt: fpisa.
     shape = x_ref.shape[1:]
 
     def body(i, acc):
-        inp = fpisa.encode(x_ref[i], fmt)
+        inp = fpisa.encode_bits(x_ref[i], fmt)
         new, _ = add(fpisa.Planes(*acc), inp, fmt)
         return (new.exp, new.man)
 
     zero = (jnp.zeros(shape, jnp.int32), jnp.zeros(shape, jnp.int32))
     exp, man = jax.lax.fori_loop(0, num_workers, body, zero)
-    out = fpisa.renormalize(fpisa.Planes(exp=exp, man=man), fmt)
-    out_ref[...] = out.astype(out_ref.dtype)
+    out_ref[...] = fpisa.renormalize_bits(
+        fpisa.Planes(exp=exp, man=man), fmt).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("variant", "fmt_name", "interpret"))
@@ -44,18 +44,20 @@ def fpisa_accum(
     fmt_name: str = "fp32",
     interpret: bool = False,
 ):
-    """x: (W, R, B) packed FP32 -> (R, B) switch-order FPISA aggregate."""
+    """x: (W, R, B) packed FP -> (R, B) f32 switch-order FPISA aggregate."""
     fmt = fpisa.FORMATS[fmt_name]
     w, r, b = x.shape
     # keep W * TILE_R * B * 4B <= ~4 MiB of VMEM for the payload tile
     budget_rows = max(8, (4 << 20) // max(1, w * b * 4))
     tile_r = min(r, budget_rows, 256)
     grid = (pl.cdiv(r, tile_r),)
-    return pl.pallas_call(
+    # integer bits in and out: see fpisa_fused
+    bits = pl.pallas_call(
         functools.partial(_accum_kernel, num_workers=w, variant=variant, fmt=fmt),
         grid=grid,
         in_specs=[pl.BlockSpec((w, tile_r, b), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((tile_r, b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, b), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((r, b), fpisa.BITS_DTYPE[fmt_name]),
         interpret=interpret,
-    )(x)
+    )(fpisa.to_bits(x, fmt))
+    return fpisa.from_bits(bits, fmt).astype(jnp.float32)

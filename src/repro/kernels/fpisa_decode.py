@@ -20,8 +20,8 @@ from repro.kernels.fpisa_encode import TILE_R
 def _decode_kernel(man_ref, bmax_ref, out_ref, *, preshift: int, fmt: fpisa.FpFormat):
     man = man_ref[...]
     e = jnp.broadcast_to(bmax_ref[...] + preshift, man.shape)  # (TILE_R,1) -> tile
-    out = fpisa.renormalize(fpisa.Planes(exp=e, man=man), fmt)
-    out_ref[...] = out.astype(jnp.float32) if fmt.name == "fp32" else out
+    out_ref[...] = fpisa.renormalize_bits(
+        fpisa.Planes(exp=e, man=man), fmt).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("preshift", "fmt_name", "interpret"))
@@ -37,8 +37,7 @@ def fpisa_decode(
     r, b = man_sum.shape
     tile_r = min(TILE_R, r)
     grid = (pl.cdiv(r, tile_r),)
-    out_dtype = {"fp32": jnp.float32, "fp16": jnp.float16, "bf16": jnp.bfloat16}[fmt_name]
-    return pl.pallas_call(
+    bits = pl.pallas_call(
         functools.partial(_decode_kernel, preshift=preshift, fmt=fmt),
         grid=grid,
         in_specs=[
@@ -46,6 +45,7 @@ def fpisa_decode(
             pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tile_r, b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, b), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((r, b), fpisa.BITS_DTYPE[fmt_name]),
         interpret=interpret,
     )(man_sum, bmax[:, None])
+    return fpisa.from_bits(bits, fmt)  # integer bits out: see fpisa_fused

@@ -31,8 +31,7 @@ TILE_R = 256
 
 
 def _extract_kernel(x_ref, exp_ref, man_ref, bmax_ref, *, fmt: fpisa.FpFormat):
-    x = x_ref[...]
-    planes = fpisa.encode(x, fmt)
+    planes = fpisa.encode_bits(x_ref[...], fmt)
     exp_ref[...] = planes.exp
     man_ref[...] = planes.man
     bmax_ref[...] = jnp.max(planes.exp, axis=-1, keepdims=True)
@@ -45,7 +44,7 @@ def _align_kernel(exp_ref, man_ref, bmax_ref, out_ref, *, preshift: int):
 
 @functools.partial(jax.jit, static_argnames=("fmt_name", "interpret"))
 def fpisa_extract(x: jax.Array, fmt_name: str = "fp32", interpret: bool = False):
-    """x: (R, B) packed FP32 -> (exp i32 (R,B), man i32 (R,B), bmax i32 (R,))."""
+    """x: (R, B) packed FP -> (exp i32 (R,B), man i32 (R,B), bmax i32 (R,))."""
     fmt = fpisa.FORMATS[fmt_name]
     r, b = x.shape
     tile_r = min(TILE_R, r)
@@ -65,7 +64,7 @@ def fpisa_extract(x: jax.Array, fmt_name: str = "fp32", interpret: bool = False)
             jax.ShapeDtypeStruct((r, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(x)
+    )(fpisa.to_bits(x, fmt))  # integer bits in: see fpisa_fused
     return exp, man, bmax[:, 0]
 
 

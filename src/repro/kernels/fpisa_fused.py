@@ -28,6 +28,10 @@ jnp op that XLA fuses with the wire-dtype cast — and the result is
 bit-identical to the reference ``extract_ref`` + ``align_ref`` composition
 against the global exponent.
 
+Both kernels read and write the packed values as same-width integer bits
+(``fpisa.to_bits``); the float bitcast happens in the jitted wrapper, where
+XLA folds it away, because Mosaic cannot load or cast f16 vectors on v5e.
+
 VMEM budget: a (TILE_R, B) f32/int32 tile is TILE_R*B*4 bytes; the fused
 encode kernel holds ~3 live tiles (x, man, plus encode temporaries) — at the
 default TILE_R=256, B=512 worst case that is ~1.5 MiB << 16 MiB VMEM.
@@ -44,12 +48,9 @@ from repro.core import fpisa
 from repro.core import numerics as nx
 from repro.kernels.fpisa_encode import TILE_R
 
-_PACKED_OUT = {"fp32": jnp.float32, "fp16": jnp.float16, "bf16": jnp.bfloat16}
-
 
 def _fused_encode_align_kernel(x_ref, man_ref, bmax_ref, *, fmt: fpisa.FpFormat):
-    x = x_ref[...]
-    planes = fpisa.encode(x, fmt)
+    planes = fpisa.encode_bits(x_ref[...], fmt)
     bmax = jnp.max(planes.exp, axis=-1, keepdims=True)  # (TILE_R, 1)
     man_ref[...] = nx.arshift(planes.man, bmax - planes.exp)
     bmax_ref[...] = bmax
@@ -58,8 +59,8 @@ def _fused_encode_align_kernel(x_ref, man_ref, bmax_ref, *, fmt: fpisa.FpFormat)
 def _fused_decode_kernel(man_ref, bmax_ref, out_ref, *, preshift: int, fmt: fpisa.FpFormat):
     man = man_ref[...].astype(jnp.int32)  # upcast narrow wire dtypes in-VMEM
     e = jnp.broadcast_to(bmax_ref[...] + preshift, man.shape)
-    out = fpisa.renormalize(fpisa.Planes(exp=e, man=man), fmt)
-    out_ref[...] = out.astype(out_ref.dtype)
+    out_ref[...] = fpisa.renormalize_bits(
+        fpisa.Planes(exp=e, man=man), fmt).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt_name", "interpret"))
@@ -88,7 +89,7 @@ def fused_encode_align(x: jax.Array, fmt_name: str = "fp32", interpret: bool = F
             jax.ShapeDtypeStruct((r, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(x)
+    )(fpisa.to_bits(x, fmt))
     return man, bmax[:, 0]
 
 
@@ -106,7 +107,7 @@ def fused_decode(
     r, b = man_sum.shape
     tile_r = min(TILE_R, r)
     grid = (pl.cdiv(r, tile_r),)
-    return pl.pallas_call(
+    bits = pl.pallas_call(
         functools.partial(_fused_decode_kernel, preshift=preshift, fmt=fmt),
         grid=grid,
         in_specs=[
@@ -114,6 +115,7 @@ def fused_decode(
             pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tile_r, b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, b), _PACKED_OUT[fmt_name]),
+        out_shape=jax.ShapeDtypeStruct((r, b), fpisa.BITS_DTYPE[fmt_name]),
         interpret=interpret,
     )(man_sum, bmax[:, None])
+    return fpisa.from_bits(bits, fmt)

@@ -11,11 +11,11 @@ import argparse
 from time import perf_counter
 
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.agg import AggConfig, add_agg_args
+from repro.launch.compile_cache import use_compile_cache
 from repro.trace import add_trace_args
 from repro.trace import from_args as trace_from_args
 from repro.data.pipeline import ShardedLoader, SyntheticCorpus
@@ -26,6 +26,52 @@ from repro.runtime.elastic import make_mesh_for
 from repro.runtime.health import HealthMonitor
 from repro.sharding import rules
 from repro.train.step import make_train_step
+
+
+def opt_config(cfg, opt_overrides: dict | None = None) -> optimizers.OptConfig:
+    """The model config's optimizer, with ``opt_overrides`` applied."""
+    return optimizers.OptConfig(**{"name": cfg.optimizer, "lr": cfg.learning_rate,
+                                   **(opt_overrides or {})})
+
+
+def state_shardings(model, cfg, mesh, opt_cfg: optimizers.OptConfig):
+    """(param shardings, optimizer-state shardings) that ``train_loop``
+    keeps the training state in, step after step."""
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pspecs = rules.param_pspecs(shapes, cfg, mesh)
+    ostate = rules.named(mesh, rules.opt_pspecs(pspecs, shapes, mesh))
+    return rules.named(mesh, pspecs), optimizers.OptState(
+        step=NamedSharding(mesh, P()), m=ostate,
+        v=ostate if opt_cfg.name == "adamw" else None)
+
+
+def build_step(cfg, mesh, agg: AggConfig, global_batch: int, *,
+               opt_overrides: dict | None = None, diagnostics: bool = False):
+    """(model, opt_cfg, step_fn): the model, the optimizer config and the
+    jitted train step that ``train_loop`` runs.
+
+    The step returns params and optimizer state in the shardings it takes
+    them in (``state_shardings``), so every step runs the one compiled
+    program, and it donates them: the update reuses their buffers instead of
+    holding two copies. ``diagnostics`` adds the per-replica and aggregated
+    gradients to the step's metrics (``repro.train.step.make_train_step``)."""
+    model = build(cfg)
+    opt_cfg = opt_config(cfg, opt_overrides)
+    pshard, oshard = state_shardings(model, cfg, mesh, opt_cfg)
+    step_fn = jax.jit(make_train_step(model, mesh, agg, opt_cfg, global_batch,
+                                      diagnostics=diagnostics),
+                      donate_argnums=(0, 1), out_shardings=(pshard, oshard, None))
+    return model, opt_cfg, step_fn
+
+
+def init_state(model, cfg, mesh, opt_cfg: optimizers.OptConfig, seed: int = 0):
+    """(params, opt_state): a random init made under jit straight into
+    ``state_shardings``, so no device holds more than its share at any
+    point."""
+    pshard, oshard = state_shardings(model, cfg, mesh, opt_cfg)
+    params = jax.jit(model.init, out_shardings=pshard)(jax.random.PRNGKey(seed))
+    opt_state = jax.jit(lambda p: optimizers.init(p, opt_cfg), out_shardings=oshard)(params)
+    return params, opt_state
 
 
 def train_loop(cfg, *, steps: int, global_batch: int, seq_len: int,
@@ -44,21 +90,9 @@ def train_loop(cfg, *, steps: int, global_batch: int, seq_len: int,
     if agg is None:
         agg = AggConfig(strategy=agg_strategy, backend=agg_backend,
                         chunk_elems=agg_chunk, bucket_bytes=agg_bucket_bytes)
-    model = build(cfg)
-    opt_kw = {"name": cfg.optimizer, "lr": cfg.learning_rate}
-    opt_kw.update(opt_overrides or {})
-    opt_cfg = optimizers.OptConfig(**opt_kw)
-
-    params = model.init(jax.random.PRNGKey(seed))
-    pspecs = rules.param_pspecs(params, cfg, mesh)
-    params = jax.device_put(params, rules.named(mesh, pspecs))
-    opt_state = optimizers.init(params, opt_cfg)
-    ospecs = rules.opt_pspecs(pspecs, params, mesh)
-    opt_state = optimizers.OptState(
-        step=jax.device_put(opt_state.step, NamedSharding(mesh, P())),
-        m=jax.device_put(opt_state.m, rules.named(mesh, ospecs)),
-        v=None if opt_state.v is None else jax.device_put(opt_state.v, rules.named(mesh, ospecs)),
-    )
+    model, opt_cfg, step_fn = build_step(cfg, mesh, agg, global_batch,
+                                         opt_overrides=opt_overrides)
+    params, opt_state = init_state(model, cfg, mesh, opt_cfg, seed)
 
     start_step = 0
     saver = None
@@ -77,16 +111,12 @@ def train_loop(cfg, *, steps: int, global_batch: int, seq_len: int,
                 # bundle and the split dirs stop mattering
                 host_params, extra = ckpt.restore(ckpt_dir, latest, params)
                 host_opt, _ = ckpt.restore(ckpt_dir + "_opt", latest, opt_state)
-            params = jax.device_put(host_params, rules.named(mesh, pspecs))
-            opt_state = optimizers.OptState(
-                step=jax.device_put(host_opt.step, NamedSharding(mesh, P())),
-                m=jax.device_put(host_opt.m, rules.named(mesh, ospecs)),
-                v=None if host_opt.v is None else jax.device_put(host_opt.v, rules.named(mesh, ospecs)),
-            )
+            pshard, oshard = state_shardings(model, cfg, mesh, opt_cfg)
+            params = jax.device_put(host_params, pshard)
+            opt_state = jax.device_put(host_opt, oshard)
             start_step = latest + 1
             print(f"[train] resumed from step {latest}")
 
-    step_fn = jax.jit(make_train_step(model, mesh, agg, opt_cfg, global_batch))
     loader = ShardedLoader(SyntheticCorpus(cfg.vocab_size, seed), global_batch, seq_len)
     bspec = rules.batch_pspec(mesh, global_batch)
     health = HealthMonitor(hosts=[0])
@@ -137,6 +167,7 @@ def main():
                          "controller (default: one per device); implies the "
                          "controller path even without --fault-plan")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     try:

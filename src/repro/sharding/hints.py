@@ -9,25 +9,15 @@ unaffected.
 from __future__ import annotations
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def _mesh_axis_names():
     """Names of AUTO axes on the active abstract mesh (manual shard_map axes
-    must not appear in sharding constraints)."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:  # noqa: BLE001
-        return ()
-    if m is None or not getattr(m, "axis_names", None):
-        return ()
-    try:
-        types = dict(zip(m.axis_names, m.axis_types))
-        return tuple(
-            a for a, t in types.items() if t == jax.sharding.AxisType.Auto
-        )
-    except Exception:  # noqa: BLE001
-        return tuple(m.axis_names)
+    must not appear in sharding constraints); () without a mesh."""
+    m = jax.sharding.get_abstract_mesh()
+    return tuple(a for a, t in zip(m.axis_names, m.axis_types)
+                 if t == AxisType.Auto)
 
 
 def batch_axes():
@@ -55,7 +45,4 @@ def constrain(x, *placements):
             parts.append(keep if keep else None)
         else:
             parts.append(pl if pl in names else None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*parts))
-    except Exception:  # pragma: no cover — constraint invalid for this mesh
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*parts))

@@ -129,8 +129,6 @@ from repro.core import fpisa
 # spell switchsim.dataplane.COUNTERS
 from repro.switchsim import COUNTERS, SLOT_STATE_FIELDS
 
-_PACKED_DTYPE = {"fp32": jnp.float32, "fp16": jnp.float16, "bf16": jnp.bfloat16}
-
 _I_PACKETS, _I_DUP, _I_STALE, _I_OVERWRITE, _I_OVERFLOW, _I_RECLAIMED, \
     _I_DENIED, _I_PREEMPTED = range(len(COUNTERS))
 
@@ -266,7 +264,7 @@ def init_state(cfg: DataplaneConfig) -> DataplaneState:
         man=jnp.zeros((g, e), jnp.int32),
         seen=jnp.zeros((g, cfg.num_workers), bool),
         slot_chunk=jnp.full((g,), -1, jnp.int32),
-        result=jnp.zeros((g, e), _PACKED_DTYPE[cfg.fmt_name]),
+        result=jnp.zeros((g, e), fpisa.PACKED_DTYPE[cfg.fmt_name]),
         result_valid=jnp.zeros((g,), bool),
         counters=jnp.zeros((cfg.num_jobs, len(COUNTERS)), jnp.int32),
         recirc=jnp.zeros((cfg.num_pipelines,), jnp.int32),
@@ -394,7 +392,7 @@ def ingest_batch(state: DataplaneState, workers, chunks, payloads, valid,
     pref = lottery_pref(cfg, now, jnp)  # constant across this call's rounds
 
     ready0 = jnp.zeros((b,), bool)
-    results0 = jnp.zeros((b, cfg.elems_per_packet), _PACKED_DTYPE[cfg.fmt_name])
+    results0 = jnp.zeros((b, cfg.elems_per_packet), fpisa.PACKED_DTYPE[cfg.fmt_name])
     accepted0 = jnp.zeros((b,), bool)
 
     def round_body(carry, pidx):

@@ -54,7 +54,7 @@ def pipeline_forward(params, batch, cfg, *, stage_axis: str, n_micro: int):
     the LOCAL stage chunk (L/n_stages, ...); other params replicated.
     Returns logits for the full batch (valid on the last stage, broadcast to
     all stages for loss uniformity)."""
-    n = compat.axis_size(stage_axis)
+    n = jax.lax.axis_size(stage_axis)
     sid = lax.axis_index(stage_axis)
     toks = batch["tokens"]
     b, s = toks.shape

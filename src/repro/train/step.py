@@ -17,8 +17,7 @@ Two execution shapes, selected by ``cfg.dp_boundary`` and the mesh:
   reduction is FPISA-integer (hierarchical aggregation, DESIGN.md §2).
 
 On a single-pod mesh with ``pod`` boundary there is no replica axis left and
-the step degrades to plain auto-jit with native reductions (recorded as such
-in EXPERIMENTS.md).
+the step degrades to plain auto-jit with native reductions.
 
 The optimizer update runs *outside* the shard_map under automatic sharding so
 ZeRO-1 ('data'-sharded m/v) resolves through XLA's partitioner.
@@ -43,13 +42,11 @@ with a trajectory equal, bit for bit, to the uninterrupted run.
 """
 from __future__ import annotations
 
-import functools
 import math
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
 from repro.core.agg import AggConfig, Aggregator
@@ -65,7 +62,7 @@ def _replica_axes(mesh: Mesh, cfg) -> tuple:
 
 def make_train_step(model, mesh: Mesh, agg: AggConfig, opt_cfg: optimizers.OptConfig,
                     global_batch: int, accum_steps: int = 1,
-                    logical_workers: int = 0):
+                    logical_workers: int = 0, diagnostics: bool = False):
     """Returns step_fn(params, opt_state, batch) -> (params, opt_state, metrics).
 
     ``accum_steps`` > 1 splits the per-device batch into microbatches and
@@ -76,9 +73,22 @@ def make_train_step(model, mesh: Mesh, agg: AggConfig, opt_cfg: optimizers.OptCo
     ``logical_workers`` > 0 selects logical-worker mode (module doc): W fixed
     aggregation ports independent of the mesh size; requires a non-native
     aggregation strategy, ``accum_steps == 1``, and a mesh whose replica
-    extent divides both W and the global batch."""
+    extent divides both W and the global batch.
+
+    The aggregator returns the SUM of the replicas' (or logical workers')
+    mean gradients; the step divides it by their count, so every strategy
+    steps on the global-batch mean gradient, as ``native`` does.
+
+    ``diagnostics`` (explicit-boundary, non-logical steps only) adds two
+    entries to the metrics: ``local_grads``, each replica's gradients before
+    aggregation stacked on a leading replica axis, and ``agg_grads``, the
+    aggregator's output (the sum, before the division). They cost a copy of
+    the gradients per replica; the program is otherwise the same step."""
     cfg = model.cfg
     boundary = _replica_axes(mesh, cfg)
+    if diagnostics and (logical_workers or not boundary or agg.strategy == "native"):
+        raise ValueError("diagnostics needs an explicit aggregation boundary with a "
+                         "non-native strategy and no logical workers")
     if logical_workers:
         if agg.strategy == "native" or not boundary:
             raise ValueError(
@@ -129,7 +139,7 @@ def make_train_step(model, mesh: Mesh, agg: AggConfig, opt_cfg: optimizers.OptCo
                 # this shard hosts k = W / replica_extent logical workers,
                 # each owning a fixed global-batch slice (contiguous: shard d
                 # hosts workers [d*k, (d+1)*k) — matches _gather_logical)
-                repl = math.prod(compat.axis_size(a) for a in boundary)
+                repl = math.prod(jax.lax.axis_size(a) for a in boundary)
                 k = logical_workers // repl
 
                 def split(leaf):
@@ -143,6 +153,7 @@ def make_train_step(model, mesh: Mesh, agg: AggConfig, opt_cfg: optimizers.OptCo
                 # stacked integer-domain aggregation over (worker, mesh) —
                 # bit-identical on any mesh dividing W (core/allreduce.py)
                 grads = aggregator.allreduce_tree(grads)
+                grads = jax.tree.map(lambda g: g / logical_workers, grads)
                 # fixed-order loss reduction: the gathered (W,) vector has the
                 # same shape and order on every mesh. The sum MUST be a scan —
                 # a jnp.sum here gets pattern-matched into a cross-device
@@ -153,28 +164,43 @@ def make_train_step(model, mesh: Mesh, agg: AggConfig, opt_cfg: optimizers.OptCo
                     lambda c, v: (c + v, None), jnp.float32(0), gathered)
                 return loss / logical_workers, grads
         else:
+            repl = math.prod(mesh.shape[a] for a in boundary)
+
             def sharded_grads(params, batch):
-                loss, grads = grads_and_loss(params, batch)
+                loss, local = grads_and_loss(params, batch)
                 # per-leaf or bucketed per agg.bucket_bytes (core/bucketer.py)
-                grads = aggregator.allreduce_tree(grads)
+                summed = aggregator.allreduce_tree(local)
+                grads = jax.tree.map(lambda g: g / repl, summed)
                 loss = jax.lax.pmean(loss, boundary)
+                if diagnostics:
+                    stacked = jax.tree.map(lambda g: g[None], local)
+                    return loss, grads, {"local_grads": stacked, "agg_grads": summed}
                 return loss, grads
 
         def batch_spec(leaf):
             return P(*( [manual_batch_axes if manual_batch_axes else None]
                        + [None] * (leaf.ndim - 1)))
 
+        # size-1 axes partition nothing, so they join the manual set: Mosaic
+        # kernels (the pallas backend) cannot be partitioned automatically
+        # and refuse to lower in a region that has any auto axis left
+        manual = set(boundary) | {a for a in mesh.axis_names if mesh.shape[a] == 1}
+
         def apply_grads(params, batch):
             in_specs = (
                 jax.tree.map(lambda _: P(), params),
                 jax.tree.map(batch_spec, batch),
             )
+            out_specs = (P(), jax.tree.map(lambda _: P(), params))
+            if diagnostics:
+                out_specs += ({"local_grads": jax.tree.map(lambda _: P(boundary), params),
+                               "agg_grads": jax.tree.map(lambda _: P(), params)},)
             return compat.shard_map(
                 sharded_grads,
                 mesh=mesh,
                 in_specs=in_specs,
-                out_specs=(P(), jax.tree.map(lambda _: P(), params)),
-                axis_names=set(boundary),
+                out_specs=out_specs,
+                axis_names=manual,
                 check_vma=False,
             )(params, batch)
     else:
@@ -183,9 +209,11 @@ def make_train_step(model, mesh: Mesh, agg: AggConfig, opt_cfg: optimizers.OptCo
             return loss, grads
 
     def train_step(params, opt_state, batch):
-        loss, grads = apply_grads(params, batch)
+        loss, grads, *diag = apply_grads(params, batch)
         params, opt_state, metrics = optimizers.update(params, grads, opt_state, opt_cfg)
         metrics["loss"] = loss
+        for d in diag:
+            metrics.update(d)
         return params, opt_state, metrics
 
     return train_step

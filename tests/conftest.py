@@ -15,6 +15,8 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     so multi-device integration tests go through here.
     """
     env = dict(os.environ)
+    # the child never touches an accelerator: the parent may hold it
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run(

@@ -68,15 +68,9 @@ from repro.sharding import rules
 from repro.train.step import make_train_step
 from repro.data.pipeline import SyntheticCorpus, ShardedLoader
 
-# Modern jax: the production-shaped 3-axis mesh, exercising the PARTIALLY
-# manual shard_map (manual replica axes + auto 'model') the real fleet uses.
-# Old-jax XLA cannot partition that shape (SPMD IsManualSubgroup check
-# failure), so there we fall back to a fully-manual pure-DP mesh — strategy
-# equivalence itself is orthogonal to TP.
-if hasattr(jax, "shard_map"):
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
-else:
-    mesh = compat.make_mesh((2, 4), ("pod", "data"))
+# the production-shaped 3-axis mesh, exercising the PARTIALLY manual
+# shard_map (manual replica axes + auto 'model') the real fleet uses
+mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_smoke_config("internlm2-20b").with_(num_kv_heads=2, num_heads=8)
 model = build(cfg)
 params0 = model.init(jax.random.PRNGKey(0))
@@ -85,7 +79,7 @@ opt_cfg = optimizers.OptConfig(name="adamw", lr=1e-3, warmup_steps=5)
 ospecs = rules.opt_pspecs(pspecs, params0, mesh)
 GB = 8
 loader = ShardedLoader(SyntheticCorpus(cfg.vocab_size), GB, 64)
-losses = {}
+losses, gnorms = {}, {}
 for strat in ["native", "fpisa", "switchml"]:
     params = jax.device_put(params0, rules.named(mesh, pspecs))
     opt = optimizers.init(params, opt_cfg)
@@ -93,17 +87,22 @@ for strat in ["native", "fpisa", "switchml"]:
                               m=jax.device_put(opt.m, rules.named(mesh, ospecs)),
                               v=jax.device_put(opt.v, rules.named(mesh, ospecs)))
     step = jax.jit(make_train_step(model, mesh, AggConfig(strategy=strat), opt_cfg, GB))
-    ls = []
+    ls, gs = [], []
     for i in range(4):
         batch = {"tokens": jax.device_put(loader.batch_at(i)["tokens"],
                                           NamedSharding(mesh, P(("pod","data"), None)))}
         params, opt, m = step(params, opt, batch)
         ls.append(float(m["loss"]))
-    losses[strat] = ls
+        gs.append(float(m["grad_norm"]))
+    losses[strat], gnorms[strat] = ls, gs
 # FPISA and SwitchML training must track native float training closely
 for strat in ("fpisa", "switchml"):
     for a, b in zip(losses[strat], losses["native"]):
         assert abs(a - b) < 1e-3, (strat, losses)
+    # every strategy steps on the global-batch MEAN gradient, as native does
+    # (the aggregators return the sum over the 4 replicas)
+    for a, b in zip(gnorms[strat], gnorms["native"]):
+        assert abs(a - b) <= 1e-2 * b, (strat, gnorms)
 # and the loss must decrease
 assert losses["fpisa"][-1] < losses["fpisa"][0]
 print("TRAIN_EQUIV_OK")

@@ -83,3 +83,15 @@ def test_kernel_pipeline_equals_core_block_path():
     man = F.block_encode(flat, be, 256, 1)
     expect = F.block_decode(man, be, 256, 1)
     assert np.array_equal(np.asarray(out).view(np.int32), np.asarray(expect).view(np.int32))
+
+
+@pytest.mark.parametrize("fmt_name", ["fp16", "bf16"])
+def test_accum_kernel_16bit_formats_match_ref(fmt_name):
+    from repro.core import fpisa
+
+    fmt = fpisa.FORMATS[fmt_name]
+    x = jnp.asarray(RNG.standard_normal((4, 64, 256)) * 0.01, fpisa.PACKED_DTYPE[fmt_name])
+    a_k = ops.accum(x, fmt_name=fmt_name)
+    a_r = ref.accum_ref(x, fmt=fmt).astype(jnp.float32)
+    assert a_k.dtype == jnp.float32
+    assert np.array_equal(np.asarray(a_k).view(np.int32), np.asarray(a_r).view(np.int32))

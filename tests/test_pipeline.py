@@ -9,14 +9,8 @@ from repro.models.registry import build
 from repro.train.pipeline import make_pp_loss, split_stages
 
 from repro import compat
-# Modern jax: ('pod','model') mesh exercising the stage axis MANUAL with the
-# TP axis auto. Old-jax XLA cannot SPMD-partition lax.axis_index (->
-# PartitionId) inside a partially-auto shard_map, so there the test runs on a
-# single-axis fully-manual mesh — the TP axis is orthogonal to the schedule.
-if hasattr(jax, "shard_map"):
-    mesh = compat.make_mesh((2, 2), ("pod", "model"))
-else:
-    mesh = compat.make_mesh((2,), ("pod",))
+# ('pod','model') mesh: the stage axis MANUAL with the TP axis auto
+mesh = compat.make_mesh((2, 2), ("pod", "model"))
 cfg = get_smoke_config("stablelm-3b").with_(num_layers=4, d_model=64)
 model = build(cfg)
 params = model.init(jax.random.PRNGKey(0))
