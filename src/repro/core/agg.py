@@ -517,14 +517,10 @@ class Aggregator:
     def allreduce(self, x: jax.Array) -> jax.Array:
         """Aggregate one array over the configured axes (leading
         logical-worker axis first when ``stacked``)."""
-        with _trace.span("agg.allreduce", strategy=self.spec.name,
-                         backend=self.backend, stacked=self.stacked) as sp:
+        with _trace.scope("agg"):
             if self.stacked:
-                out = _dispatch_stacked(x, self.axes, self.cfg)
-            else:
-                out = _dispatch(x, self.axes, self.cfg)
-            sp.sync(out)
-        return out
+                return _dispatch_stacked(x, self.axes, self.cfg)
+            return _dispatch(x, self.axes, self.cfg)
 
     def allreduce_tree(self, tree):
         """Aggregate every leaf of a gradient pytree.
@@ -539,9 +535,7 @@ class Aggregator:
         The tree goes in and comes out through an optimization barrier, in
         row-major layout (``_row_major``), so the code around the aggregation
         compiles the same whichever backend runs inside it."""
-        with _trace.span("agg.allreduce_tree", strategy=self.spec.name,
-                         backend=self.backend, stacked=self.stacked,
-                         bucket_bytes=self.cfg.bucket_bytes) as sp:
+        with _trace.scope("agg"):
             tree = lax.optimization_barrier(_row_major(tree))
             if self.cfg.bucket_bytes:
                 from repro.core import bucketer
@@ -554,9 +548,7 @@ class Aggregator:
                         tree, self.axes, self.cfg)
             else:
                 out = jax.tree_util.tree_map(self.allreduce, tree)
-            out = _row_major(lax.optimization_barrier(out))
-            sp.sync(out)
-        return out
+            return _row_major(lax.optimization_barrier(out))
 
 
 def _row_major(tree):

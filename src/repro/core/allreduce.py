@@ -90,6 +90,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import trace as _trace
 from repro.core import agg as _agg
 from repro.core.agg import (  # noqa: F401  (re-exported legacy surface)
     AggConfig, BACKENDS, DEFAULT_BLOCK, register_strategy, resolve_backend,
@@ -284,18 +285,19 @@ def fpisa_allreduce(x: jax.Array, axis_names: Sequence[str], cfg: AggConfig):
     fmt = cfg.fmt
     backend = resolve_backend(cfg.backend)
     orig_shape, orig_dtype = x.shape, x.dtype
-    flat, pad = _flatten_pad(x.astype(_PACKED[cfg.fmt_name]), cfg.block)
-
     shift = _wire_shift(fmt, w, cfg.wire_bits)
-    # The per-block max-exponent pmax inside _encode_align is a tiny
-    # collective: one int per block (1/block of the data, and it can ride in
-    # int8 on real hardware). Unlike SwitchML this is NOT a host round trip;
-    # it pipelines with the mantissa pass chunk-by-chunk.
-    man, bmax = _encode_align(flat, axes, shift, cfg, backend)
-    man = _wire_cast(man, cfg.wire_bits)
-    man_sum = lax.psum(man, axes)
-    out = _decode(man_sum, bmax, shift, cfg, backend)
-    return _unflatten(out, pad, orig_shape, orig_dtype)
+    with _trace.scope("agg.encode"):
+        flat, pad = _flatten_pad(x.astype(_PACKED[cfg.fmt_name]), cfg.block)
+        # The per-block max-exponent pmax inside _encode_align is a tiny
+        # collective: one int per block (1/block of the data, and it can ride
+        # in int8 on real hardware). Unlike SwitchML this is NOT a host round
+        # trip; it pipelines with the mantissa pass chunk-by-chunk.
+        man, bmax = _encode_align(flat, axes, shift, cfg, backend)
+    with _trace.scope("agg.psum"):
+        man_sum = lax.psum(_wire_cast(man, cfg.wire_bits), axes)
+    with _trace.scope("agg.decode"):
+        out = _decode(man_sum, bmax, shift, cfg, backend)
+        return _unflatten(out, pad, orig_shape, orig_dtype)
 
 
 def _hier_collect(man: jax.Array, data_axis: str, pod_axis: str,
@@ -364,20 +366,22 @@ def fpisa_allreduce_hierarchical(
     fmt = cfg.fmt
     backend = resolve_backend(cfg.backend)
     orig_shape, orig_dtype = x.shape, x.dtype
-    # pad to block * w_data so reduce_scatter tiles evenly
-    quantum = cfg.block * w_data
-    flat = x.reshape(-1).astype(_PACKED[cfg.fmt_name])
-    pad = (-flat.shape[0]) % quantum
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-
     shift = _wire_shift(fmt, w, cfg.wire_bits)
-    # exponent agreement is global (pmax over both axes) so mantissa scales
-    # are compatible across both reduction levels
-    man, bmax = _encode_align(flat, (data_axis, pod_axis), shift, cfg, backend)
-    man_shard, pod_shift = _hier_collect(man, data_axis, pod_axis, cfg, shift)
-    out = _hier_finish(man_shard, bmax, shift, pod_shift, data_axis, cfg, backend)
-    return _unflatten(out, pad, orig_shape, orig_dtype)
+    with _trace.scope("agg.encode"):
+        # pad to block * w_data so reduce_scatter tiles evenly
+        quantum = cfg.block * w_data
+        flat = x.reshape(-1).astype(_PACKED[cfg.fmt_name])
+        pad = (-flat.shape[0]) % quantum
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        # exponent agreement is global (pmax over both axes) so mantissa
+        # scales are compatible across both reduction levels
+        man, bmax = _encode_align(flat, (data_axis, pod_axis), shift, cfg, backend)
+    with _trace.scope("agg.psum"):
+        man_shard, pod_shift = _hier_collect(man, data_axis, pod_axis, cfg, shift)
+    with _trace.scope("agg.decode"):
+        out = _hier_finish(man_shard, bmax, shift, pod_shift, data_axis, cfg, backend)
+        return _unflatten(out, pad, orig_shape, orig_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -512,15 +516,17 @@ def stacked_fpisa_allreduce(x, axis_names: Sequence[str], cfg: AggConfig):
     w = k * _axis_size(axes)
     backend = resolve_backend(cfg.backend)
     orig_shape, orig_dtype = x.shape[1:], x.dtype
-    rows, pad = _stacked_pad(_stacked_rows(x, _PACKED[cfg.fmt_name]), cfg.block)
-
     shift = _wire_shift(cfg.fmt, w, cfg.wire_bits)
-    man, bmax = _encode_align_stacked(rows, axes, shift, cfg, backend)
-    man = _wire_cast(man, cfg.wire_bits)  # per-worker wire payloads
-    local = _wire_cast(jnp.sum(man.astype(jnp.int32), axis=0), cfg.wire_bits)
-    man_sum = lax.psum(local, axes)
-    out = _decode(man_sum, bmax, shift, cfg, backend)
-    return _unflatten(out, pad, orig_shape, orig_dtype)
+    with _trace.scope("agg.encode"):
+        rows, pad = _stacked_pad(_stacked_rows(x, _PACKED[cfg.fmt_name]), cfg.block)
+        man, bmax = _encode_align_stacked(rows, axes, shift, cfg, backend)
+    with _trace.scope("agg.psum"):
+        man = _wire_cast(man, cfg.wire_bits)  # per-worker wire payloads
+        local = _wire_cast(jnp.sum(man.astype(jnp.int32), axis=0), cfg.wire_bits)
+        man_sum = lax.psum(local, axes)
+    with _trace.scope("agg.decode"):
+        out = _decode(man_sum, bmax, shift, cfg, backend)
+        return _unflatten(out, pad, orig_shape, orig_dtype)
 
 
 def stacked_switchml_allreduce(x, axis_names: Sequence[str], cfg: AggConfig):
@@ -616,16 +622,20 @@ def _fpisa_flat_phases(axes, cfg: AggConfig, backend: str):
     shift = _wire_shift(cfg.fmt, w, cfg.wire_bits)
 
     def encode(flat):
-        man, bmax = _encode_align(flat, axes, shift, cfg, backend)
-        return _wire_cast(man, cfg.wire_bits), bmax
+        with _trace.scope("agg.encode"):
+            man, bmax = _encode_align(flat, axes, shift, cfg, backend)
+        with _trace.scope("agg.psum"):
+            return _wire_cast(man, cfg.wire_bits), bmax
 
     def collect(state):
         man, bmax = state
-        return lax.psum(man, axes), bmax
+        with _trace.scope("agg.psum"):
+            return lax.psum(man, axes), bmax
 
     def finish(state):
         man_sum, bmax = state
-        return _decode(man_sum, bmax, shift, cfg, backend)
+        with _trace.scope("agg.decode"):
+            return _decode(man_sum, bmax, shift, cfg, backend)
 
     return encode, collect, finish
 
@@ -647,30 +657,33 @@ def _fpisa_hier_phases(data_axis, pod_axis, cfg: AggConfig, backend: str,
     quantum = cfg.block * w_data
 
     def encode(flat):
-        pad = (-flat.shape[0]) % quantum
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        roll = (stripe % w_data) * (flat.shape[0] // w_data)
-        if roll:
-            flat = jnp.roll(flat, -roll)
-        man, bmax = _encode_align(
-            flat, (data_axis, pod_axis), shift, cfg, backend)
-        return man, bmax, pad, roll
+        with _trace.scope("agg.encode"):
+            pad = (-flat.shape[0]) % quantum
+            if pad:
+                flat = jnp.pad(flat, (0, pad))
+            roll = (stripe % w_data) * (flat.shape[0] // w_data)
+            if roll:
+                flat = jnp.roll(flat, -roll)
+            man, bmax = _encode_align(
+                flat, (data_axis, pod_axis), shift, cfg, backend)
+            return man, bmax, pad, roll
 
     def collect(state):
         man, bmax, pad, roll = state
-        man_shard, pod_shift = _hier_collect(man, data_axis, pod_axis, cfg, shift)
+        with _trace.scope("agg.psum"):
+            man_shard, pod_shift = _hier_collect(man, data_axis, pod_axis, cfg, shift)
         return man_shard, bmax, pod_shift, pad, roll
 
     def finish(state):
         man_shard, bmax, pod_shift, pad, roll = state
-        out = _hier_finish(man_shard, bmax, shift, pod_shift, data_axis,
-                           cfg, backend)
-        if roll:
-            out = jnp.roll(out, roll)
-        if pad:
-            out = out[:out.shape[0] - pad]
-        return out
+        with _trace.scope("agg.decode"):
+            out = _hier_finish(man_shard, bmax, shift, pod_shift, data_axis,
+                               cfg, backend)
+            if roll:
+                out = jnp.roll(out, roll)
+            if pad:
+                out = out[:out.shape[0] - pad]
+            return out
 
     return encode, collect, finish
 
@@ -683,19 +696,23 @@ def _fpisa_stacked_phases(axes, cfg: AggConfig, backend: str, k: int):
     shift = _wire_shift(cfg.fmt, w, cfg.wire_bits)
 
     def encode(buf):  # (k, elems) packed FP
-        man, bmax = _encode_align_stacked(buf, axes, shift, cfg, backend)
-        man = _wire_cast(man, cfg.wire_bits)
-        local = _wire_cast(jnp.sum(man.astype(jnp.int32), axis=0),
-                           cfg.wire_bits)
-        return local, bmax
+        with _trace.scope("agg.encode"):
+            man, bmax = _encode_align_stacked(buf, axes, shift, cfg, backend)
+        with _trace.scope("agg.psum"):
+            man = _wire_cast(man, cfg.wire_bits)
+            local = _wire_cast(jnp.sum(man.astype(jnp.int32), axis=0),
+                               cfg.wire_bits)
+            return local, bmax
 
     def collect(state):
         man, bmax = state
-        return lax.psum(man, axes), bmax
+        with _trace.scope("agg.psum"):
+            return lax.psum(man, axes), bmax
 
     def finish(state):
         man_sum, bmax = state
-        return _decode(man_sum, bmax, shift, cfg, backend)
+        with _trace.scope("agg.decode"):
+            return _decode(man_sum, bmax, shift, cfg, backend)
 
     return encode, collect, finish
 

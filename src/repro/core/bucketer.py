@@ -210,38 +210,23 @@ def _stream_buckets(plan: BucketPlan, flat_leaves: dict, cfg: AggConfig,
 
     def land(entry):
         bucket, state, finish = entry
-        with _trace.span("bucketer.finish", phase="finish",
-                         bucket=bucket.index, elems=bucket.elems,
-                         group=bucket.group) as sp:
+        with _trace.scope("agg.decode"):
             out = finish(state) if finish is not None else state
-            sp.sync(out)
-        unpack_bucket(bucket, out, pieces)
+            unpack_bucket(bucket, out, pieces)
 
     for bucket in plan.buckets:
         phases = phases_for(bucket)
         if phases is not None:
             encode, collect, finish = phases
-            with _trace.span("bucketer.encode", phase="encode",
-                             bucket=bucket.index, elems=bucket.elems,
-                             group=bucket.group) as sp:
-                buf = pack_fn(bucket, _stage_dtype(cfg, bucket.group))
-                state = encode(buf)
-                sp.sync(state)
+            with _trace.scope("agg.encode"):
+                state = encode(pack_fn(bucket, _stage_dtype(cfg, bucket.group)))
             if inflight is not None:
                 land(inflight)
-            with _trace.span("bucketer.collective", phase="collective",
-                             bucket=bucket.index, elems=bucket.elems,
-                             group=bucket.group) as sp:
-                collected = collect(state)
-                sp.sync(collected)
-            inflight = (bucket, collected, finish)
+            inflight = (bucket, collect(state), finish)
         else:
-            with _trace.span("bucketer.dispatch", phase="dispatch",
-                             bucket=bucket.index, elems=bucket.elems,
-                             group=bucket.group) as sp:
+            with _trace.scope("agg.encode"):
                 buf = pack_fn(bucket, _stage_dtype(cfg, bucket.group))
-                out = generic_fn(buf)
-                sp.sync(out)
+            out = generic_fn(buf)
             if inflight is not None:
                 land(inflight)
             inflight = (bucket, out, None)
@@ -255,8 +240,9 @@ def _reassemble(leaves, treedef, results: dict, pieces: dict, shape_of):
         if i in results:
             continue
         ps = sorted(pieces[i], key=lambda t: t[0])
-        flat = jnp.concatenate([p for _, p in ps]) if len(ps) > 1 else ps[0][1]
-        results[i] = flat.reshape(shape_of(leaf)).astype(leaf.dtype)
+        with _trace.scope("agg.decode"):
+            flat = jnp.concatenate([p for _, p in ps]) if len(ps) > 1 else ps[0][1]
+            results[i] = flat.reshape(shape_of(leaf)).astype(leaf.dtype)
     return jax.tree_util.tree_unflatten(
         treedef, [results[i] for i in range(len(leaves))])
 

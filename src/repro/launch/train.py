@@ -16,6 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, get_smoke_config
 from repro.core.agg import AggConfig, add_agg_args
 from repro.launch.compile_cache import use_compile_cache
+from repro import trace
 from repro.trace import add_trace_args
 from repro.trace import from_args as trace_from_args
 from repro.data.pipeline import ShardedLoader, SyntheticCorpus
@@ -85,7 +86,9 @@ def train_loop(cfg, *, steps: int, global_batch: int, seq_len: int,
 
     Aggregation is configured by ONE ``AggConfig`` (``agg``); the loose
     ``agg_*`` keyword args are retained for backwards compatibility and are
-    ignored when ``agg`` is given."""
+    ignored when ``agg`` is given. With tracing on (``--trace``), each step
+    records four host spans: ``train.batch``, ``train.place``, ``train.step``
+    (the dispatch) and ``train.fetch`` (the wait for the loss)."""
     mesh = mesh or make_mesh_for()
     if agg is None:
         agg = AggConfig(strategy=agg_strategy, backend=agg_backend,
@@ -126,10 +129,14 @@ def train_loop(cfg, *, steps: int, global_batch: int, seq_len: int,
     history = []
     for step in range(start_step, steps):
         t0 = perf_counter()
-        batch = {"tokens": jax.device_put(
-            loader.batch_at(step)["tokens"], NamedSharding(mesh, P(*bspec, None)))}
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
-        loss = float(metrics["loss"])
+        with trace.span("train.batch", step=step):
+            tokens = loader.batch_at(step)["tokens"]
+        with trace.span("train.place", step=step):
+            batch = {"tokens": jax.device_put(tokens, NamedSharding(mesh, P(*bspec, None)))}
+        with trace.span("train.step", step=step):
+            params, opt_state, metrics = step_fn(params, opt_state, batch)
+        with trace.span("train.fetch", step=step):
+            loss = float(metrics["loss"])
         dt = perf_counter() - t0
         health.heartbeat(0, dt)
         history.append(loss)

@@ -22,6 +22,7 @@ from typing import Any, Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.models import attention as attn
 from repro.models import mamba2, moe
 from repro.sharding import hints
@@ -127,17 +128,19 @@ def _sp(x, cfg):
 
 
 def _dense_block(lp, x, cfg, positions):
-    h = attn.attention_train(lp["attn"], rms_norm(x, lp["ln1"]["w"], cfg.norm_eps), cfg, positions)
-    x = _sp(x + h, cfg)
-    y = rms_norm(x, lp["ln2"]["w"], cfg.norm_eps)
-    aux = jnp.float32(0)
-    if "moe" in lp:
-        out, aux = moe.apply_moe(lp["moe"], y, cfg)
-        if "dense_mlp" in lp:
-            out = out + apply_mlp(lp["dense_mlp"], y, cfg)
-    else:
-        out = apply_mlp(lp["mlp"], y, cfg)
-    return _sp(x + out, cfg), aux
+    with trace.scope("model.attn"):
+        h = attn.attention_train(lp["attn"], rms_norm(x, lp["ln1"]["w"], cfg.norm_eps), cfg, positions)
+        x = _sp(x + h, cfg)
+    with trace.scope("model.mlp"):
+        y = rms_norm(x, lp["ln2"]["w"], cfg.norm_eps)
+        aux = jnp.float32(0)
+        if "moe" in lp:
+            out, aux = moe.apply_moe(lp["moe"], y, cfg)
+            if "dense_mlp" in lp:
+                out = out + apply_mlp(lp["dense_mlp"], y, cfg)
+        else:
+            out = apply_mlp(lp["mlp"], y, cfg)
+        return _sp(x + out, cfg), aux
 
 
 def _mamba_block(lp, x, cfg):
@@ -151,18 +154,19 @@ def _mamba_block(lp, x, cfg):
 
 
 def _input_embeds(params, batch, cfg):
-    toks = batch["tokens"]
-    x = embed(params["embed"], toks)
-    if cfg.family == "vlm":
-        patches = jnp.einsum("bpd,de->bpe", batch["patch_embeds"].astype(x.dtype),
-                             params["vlm_proj"]["w"])
-        x = jnp.concatenate([patches, x], axis=1)
-    # anchor the activation layout: batch over replica axes, d_model
-    # replicated (TP reshards at the einsums). Without this anchor the
-    # vocab-sharded embedding gather can leave the batch axis replicated
-    # (kimi dry-run: 107 GB/device saved-activation stacks).
-    x = hints.constrain(x, "batch", None, None)
-    return x.astype(jnp.dtype(cfg.activation_dtype))
+    with trace.scope("model.embed"):
+        toks = batch["tokens"]
+        x = embed(params["embed"], toks)
+        if cfg.family == "vlm":
+            patches = jnp.einsum("bpd,de->bpe", batch["patch_embeds"].astype(x.dtype),
+                                 params["vlm_proj"]["w"])
+            x = jnp.concatenate([patches, x], axis=1)
+        # anchor the activation layout: batch over replica axes, d_model
+        # replicated (TP reshards at the einsums). Without this anchor the
+        # vocab-sharded embedding gather can leave the batch axis replicated
+        # (kimi dry-run: 107 GB/device saved-activation stacks).
+        x = hints.constrain(x, "batch", None, None)
+        return x.astype(jnp.dtype(cfg.activation_dtype))
 
 
 def forward(params, batch, cfg):
@@ -202,23 +206,25 @@ def forward(params, batch, cfg):
 
         x, _ = jax.lax.scan(_remat(tail, cfg), x, params["tail_layers"])
 
-    x = rms_norm(x, params["final_norm"]["w"], cfg.norm_eps)
-    w = params["embed"]["tok"].T if cfg.tie_embeddings else params["head"]["w"]
-    logits = jnp.einsum("bsd,dv->bsv", x, w)
-    logits = hints.constrain(logits, "batch", None, "model")
+    with trace.scope("model.head"):
+        x = rms_norm(x, params["final_norm"]["w"], cfg.norm_eps)
+        w = params["embed"]["tok"].T if cfg.tie_embeddings else params["head"]["w"]
+        logits = jnp.einsum("bsd,dv->bsv", x, w)
+        logits = hints.constrain(logits, "batch", None, "model")
     return logits, aux_total
 
 
 def loss_fn(params, batch, cfg):
     logits, aux = forward(params, batch, cfg)
     toks = batch["tokens"]
-    if cfg.family == "vlm":
-        npatch = batch["patch_embeds"].shape[1]
-        logits = logits[:, npatch:, :]
-    lp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
-    tgt = toks[:, 1:]
-    nll = -jnp.take_along_axis(lp, tgt[..., None], axis=-1)[..., 0]
-    return nll.mean() + 0.01 * aux
+    with trace.scope("model.head"):
+        if cfg.family == "vlm":
+            npatch = batch["patch_embeds"].shape[1]
+            logits = logits[:, npatch:, :]
+        lp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
+        tgt = toks[:, 1:]
+        nll = -jnp.take_along_axis(lp, tgt[..., None], axis=-1)[..., 0]
+        return nll.mean() + 0.01 * aux
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import trace
+
 
 @dataclasses.dataclass(frozen=True)
 class OptConfig:
@@ -55,35 +57,37 @@ def _clip(grads, max_norm):
 
 
 def update(params, grads, state: OptState, cfg: OptConfig):
-    """Returns (new_params, new_state, metrics)."""
-    step = state.step + 1
-    lr = _schedule(cfg, step)
-    grads, gnorm = _clip(grads, cfg.grad_clip)
+    """Returns (new_params, new_state, metrics); the device work is named
+    ``optim`` (``repro.trace.SCOPES``)."""
+    with trace.scope("optim"):
+        step = state.step + 1
+        lr = _schedule(cfg, step)
+        grads, gnorm = _clip(grads, cfg.grad_clip)
 
-    if cfg.name == "adamw":
-        b1, b2 = cfg.b1, cfg.b2
-        m = jax.tree.map(lambda mm, g: b1 * mm + (1 - b1) * g, state.m, grads)
-        v = jax.tree.map(lambda vv, g: b2 * vv + (1 - b2) * g * g, state.v, grads)
-        c1 = 1 - b1 ** step.astype(jnp.float32)
-        c2 = 1 - b2 ** step.astype(jnp.float32)
+        if cfg.name == "adamw":
+            b1, b2 = cfg.b1, cfg.b2
+            m = jax.tree.map(lambda mm, g: b1 * mm + (1 - b1) * g, state.m, grads)
+            v = jax.tree.map(lambda vv, g: b2 * vv + (1 - b2) * g * g, state.v, grads)
+            c1 = 1 - b1 ** step.astype(jnp.float32)
+            c2 = 1 - b2 ** step.astype(jnp.float32)
 
-        def upd(p, mm, vv):
-            u = (mm / c1) / (jnp.sqrt(vv / c2) + cfg.eps)
-            pf = p.astype(jnp.float32)
-            pf = pf - lr * (u + cfg.weight_decay * pf)
-            return pf.astype(p.dtype)
+            def upd(p, mm, vv):
+                u = (mm / c1) / (jnp.sqrt(vv / c2) + cfg.eps)
+                pf = p.astype(jnp.float32)
+                pf = pf - lr * (u + cfg.weight_decay * pf)
+                return pf.astype(p.dtype)
 
-        new_params = jax.tree.map(upd, params, m, v)
-        new_state = OptState(step=step, m=m, v=v)
-    elif cfg.name == "sgdm":
-        m = jax.tree.map(lambda mm, g: cfg.momentum * mm + g, state.m, grads)
+            new_params = jax.tree.map(upd, params, m, v)
+            new_state = OptState(step=step, m=m, v=v)
+        elif cfg.name == "sgdm":
+            m = jax.tree.map(lambda mm, g: cfg.momentum * mm + g, state.m, grads)
 
-        def upd(p, mm):
-            pf = p.astype(jnp.float32) - lr * mm
-            return pf.astype(p.dtype)
+            def upd(p, mm):
+                pf = p.astype(jnp.float32) - lr * mm
+                return pf.astype(p.dtype)
 
-        new_params = jax.tree.map(upd, params, m)
-        new_state = OptState(step=step, m=m, v=None)
-    else:
-        raise ValueError(cfg.name)
-    return new_params, new_state, {"grad_norm": gnorm, "lr": lr}
+            new_params = jax.tree.map(upd, params, m)
+            new_state = OptState(step=step, m=m, v=None)
+        else:
+            raise ValueError(cfg.name)
+        return new_params, new_state, {"grad_norm": gnorm, "lr": lr}

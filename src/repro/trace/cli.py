@@ -7,8 +7,13 @@ of re-declaring ``--trace`` flags by hand::
     args = ap.parse_args()
     session = trace.from_args(args)
     ...                      # instrumented code records spans
-    session.finish()         # writes --trace-out (JSONL, or chrome when the
-                             # path ends in .chrome.json) and prints a line
+    session.finish()         # writes --trace-out and prints a line
+
+``--trace-out`` ending in ``.jsonl`` gets the recorded spans as JSONL (what
+``--bucket-bytes auto`` reads); any other path is a directory, and the run
+between ``from_args`` and ``finish`` is captured there by the JAX profiler:
+the device ops, with the program's scopes in their metadata, and the host
+spans beside them (``TraceAnnotation``), on one clock.
 
 ``from_args`` enables the GLOBAL tracer, so instrumentation deep in
 core/switchsim/serve/runtime records without any handle threading.
@@ -17,6 +22,8 @@ from __future__ import annotations
 
 import argparse
 
+import jax
+
 from repro.trace import export, tracer
 
 
@@ -24,14 +31,14 @@ def add_trace_args(parser: argparse.ArgumentParser):
     g = parser.add_argument_group("tracing", "span tracer (repro.trace)")
     g.add_argument(
         "--trace", action="store_true",
-        help="record per-phase timing spans (agg/bucketer/switchsim/serve/"
-             "runtime); implied by --trace-out")
+        help="record host timing spans (train loop/switchsim/serve/runtime); "
+             "implied by --trace-out")
     g.add_argument(
         "--trace-out", default=None, metavar="PATH",
-        help="write the recorded spans here on exit: JSONL with a schema "
-             "header (feeds 'python -m repro.autotune' / --bucket-bytes "
-             "auto), or chrome://tracing JSON when PATH ends in "
-             ".chrome.json")
+        help="a PATH ending in .jsonl gets the recorded spans as JSONL with a "
+             "schema header (feeds 'python -m repro.autotune' / --bucket-bytes "
+             "auto); any other PATH is a directory for a JAX profiler capture "
+             "of the run (device ops and host spans on one clock)")
     g.add_argument(
         "--trace-capacity", type=int, default=tracer._DEFAULT_CAPACITY,
         metavar="N", help="ring-buffer capacity in spans (oldest dropped)")
@@ -39,28 +46,30 @@ def add_trace_args(parser: argparse.ArgumentParser):
 
 
 class TraceSession:
-    """Handle returned by :func:`from_args`; ``finish()`` flushes the file."""
+    """Handle returned by :func:`from_args`; ``finish()`` flushes the file or
+    stops the profiler capture."""
 
     def __init__(self, enabled: bool, path: str | None, capacity: int):
         self.path = path
         self.enabled = enabled
-        if enabled:
-            self.tracer = tracer.enable(capacity)
-        else:
-            self.tracer = None
+        self.capture = bool(path) and not str(path).endswith(".jsonl")
+        self.tracer = tracer.enable(capacity) if enabled else None
+        if self.capture:
+            jax.profiler.start_trace(str(path))
 
     def finish(self) -> str | None:
-        """Write ``--trace-out`` (if any) and disable the global tracer.
-        Returns the path written, or None."""
+        """Write ``--trace-out`` (if any), stop the capture and disable the
+        global tracer. Returns the path written, or None."""
         if not self.enabled:
             return None
         tracer.disable()
         tr = self.tracer
+        if self.capture:
+            jax.profiler.stop_trace()
+            print(f"trace: profiler capture ({len(tr.spans)} spans) -> {self.path}")
+            return str(self.path)
         if self.path:
-            if str(self.path).endswith(".chrome.json"):
-                out = export.write_chrome(tr, self.path)
-            else:
-                out = export.write_jsonl(tr, self.path)
+            out = export.write_jsonl(tr, self.path)
             print(f"trace: {len(tr.spans)} spans -> {out}"
                   + (f" ({tr.dropped} dropped)" if tr.dropped else ""))
             return out

@@ -1,11 +1,12 @@
-"""Trace serialization: JSONL (the cost-model interchange format) and
-chrome://tracing (the human one). Schema documented in DESIGN.md §13.
+"""Trace serialization: JSONL, the cost-model interchange format. Schema
+documented in DESIGN.md §13. (For a timeline, capture a profiler trace:
+enabled spans appear in it as ``TraceAnnotation`` events.)
 
 JSONL layout — line 1 is a header object carrying the schema version, every
 following line is one span dict::
 
     {"schema": 1, "kind": "repro-trace", "clock": "perf_counter", ...}
-    {"name": "bucketer.encode", "id": 3, "parent": 2, "depth": 1, ...}
+    {"name": "autotune.probe", "id": 3, "parent": 2, "depth": 1, ...}
 
 ``read_jsonl`` refuses files whose header major version it does not know, so
 a cost model never silently fits fields that changed meaning.
@@ -65,31 +66,3 @@ def read_jsonl(path) -> tuple[dict, list[dict]]:
             f"{path} has trace schema {head.get('schema')!r}; this reader "
             f"understands schema {SCHEMA_VERSION}")
     return head, [json.loads(ln) for ln in lines[1:]]
-
-
-def to_chrome(trace: Tracer | Iterable[dict]) -> dict:
-    """chrome://tracing / Perfetto "trace event" JSON (complete 'X' events;
-    perf_counter seconds -> microsecond timestamps)."""
-    events = []
-    for sp in _spans_of(trace):
-        events.append({
-            "name": sp["name"],
-            "ph": "X",
-            "ts": sp["ts"] * 1e6,
-            "dur": sp["dur"] * 1e6,
-            "pid": 0,
-            "tid": sp.get("tid", 0),
-            "cat": str(sp.get("tags", {}).get("phase", "span")),
-            "args": sp.get("tags", {}),
-        })
-    return {
-        "traceEvents": events,
-        "displayTimeUnit": "ms",
-        "otherData": header(),
-    }
-
-
-def write_chrome(trace: Tracer | Iterable[dict], path) -> str:
-    with open(path, "w") as f:
-        json.dump(to_chrome(trace), f)
-    return str(path)

@@ -1,33 +1,36 @@
 """Lightweight span tracer: nestable context-manager spans over
 ``time.perf_counter`` with a ring-buffer recorder (DESIGN.md §13).
 
-The tracer exists so the per-phase costs of the aggregation pipeline —
-encode / collective / finish in ``core/bucketer.py``, switchsim rounds,
-serve prefill/decode, controller recovery — can be recorded from ordinary
-runs and replayed by the cost-model autotuner (``repro.autotune``). Design
-constraints, in order:
+Spans time HOST work: the train loop's batch/place/step/fetch, switchsim
+rounds, serve prefill/decode, controller recovery, the autotune replay
+profiler's synced phases. Device work inside a jitted program is named with
+``repro.trace.scope`` instead (a ``jax.named_scope``), because the Python
+body of a jitted function runs only while it is traced. Design constraints,
+in order:
 
 1. **Near-zero disabled path.** Instrumentation lives in hot loops that run
    with tracing off in production. ``span()`` with the tracer disabled is one
    attribute load, one bool test, and the return of a shared no-op singleton
-   — no allocation, no clock read (bound pinned by tests/test_trace.py).
-2. **Attribution through sync boundaries.** jax dispatch is asynchronous: a
+   — no allocation, no clock read (pinned by tests/test_trace.py).
+2. **One clock with the device.** An enabled span also enters a
+   ``jax.profiler.TraceAnnotation`` of its name, so a profiler capture shows
+   the program's host spans beside the device ops, on the profiler's clock.
+3. **Attribution through sync boundaries.** jax dispatch is asynchronous: a
    ``perf_counter`` pair around an eager op measures dispatch, not device
    work. A span therefore exposes ``sync(value)`` which calls
    ``jax.block_until_ready`` *inside* the span, so the device work lands in
    the span that issued it. Under a jit trace the values are abstract
    Tracers — sync detects that, skips the block, and leaves the span marked
    ``synced=False`` so the cost model can ignore trace-time artifacts.
-3. **Bounded memory.** Spans land in a ``deque(maxlen=capacity)`` ring:
+4. **Bounded memory.** Spans land in a ``deque(maxlen=capacity)`` ring:
    long-running jobs keep the most recent ``capacity`` spans and never grow.
 
 Spans are used in the ``with`` form only (enforced by the ``timing-
 discipline`` lint rule — a bare ``.start()`` with a forgotten end corrupts
 the nesting stack)::
 
-    with trace.span("bucketer.encode", bucket=i, phase="encode") as sp:
-        state = encode(buf)
-        sp.sync(state)
+    with trace.span("autotune.probe", phase="encode", elems=n) as sp:
+        state = sp.sync(encode_fn(x))
 """
 from __future__ import annotations
 
@@ -36,20 +39,18 @@ import threading
 from collections import deque
 from time import perf_counter
 
+import jax
+from jax.profiler import TraceAnnotation
+
 SCHEMA_VERSION = 1
 
 _DEFAULT_CAPACITY = 1 << 16
 
 
 def _block_until_ready(value) -> bool:
-    """Block on a pytree of device values; False when abstract (jit trace).
-
-    jax is imported lazily so the tracer stays importable (and the switchsim
-    host-callback paths stay jax-free) when no span ever syncs."""
+    """Block on a pytree of device values; False when abstract (jit trace)."""
     if value is None:
         return False
-    import jax
-
     leaves = jax.tree_util.tree_leaves(value)
     if not leaves:
         return False
@@ -88,7 +89,7 @@ class Span:
     """One timed region. Context-manager only (see module doc)."""
 
     __slots__ = ("name", "tags", "sid", "parent", "depth", "tid",
-                 "t0", "t1", "synced", "_tracer")
+                 "t0", "t1", "synced", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, tags: dict):
         self._tracer = tracer
@@ -101,6 +102,7 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.synced = False
+        self._annotation = None
 
     def __bool__(self):
         return True
@@ -136,11 +138,14 @@ class Span:
         self.depth = len(stack)
         self.tid = threading.get_ident()
         stack.append(self)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.t0 = perf_counter()
         return self
 
     def end(self) -> None:
         self.t1 = perf_counter()
+        self._annotation.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
