@@ -24,14 +24,15 @@ from repro.kernels.fpisa_fused import fused_decode, fused_encode_align
 
 
 def interpret() -> bool:
-    """Whether the Pallas kernels run in interpret mode: on the CPU backend
-    yes, on a TPU no (Mosaic). Any other platform raises instead of quietly
+    """Whether the Pallas kernels (FPISA's, and the flash-attention kernel
+    of ``models/attention``) run in interpret mode: on the CPU backend yes,
+    on a TPU no (Mosaic). Any other platform raises instead of quietly
     running the TPU kernels through the interpreter."""
     platform = jax.default_backend()
     if platform not in ("cpu", "tpu"):
         raise RuntimeError(
-            f"the FPISA Pallas kernels target TPU (Mosaic) or CPU (interpret "
-            f"mode); platform {platform!r} is neither — use backend='jnp'")
+            f"the Pallas kernels target TPU (Mosaic) or CPU (interpret mode); "
+            f"platform {platform!r} is neither — for FPISA use backend='jnp'")
     return platform == "cpu"
 
 

@@ -6,6 +6,12 @@ with online-softmax state — so HLO FLOPs equal the true causal FLOPs (no
 wasted upper-triangle work) and peak memory is O(B*H*Cq*Ck) per step instead
 of O(B*H*S^2). This matters for prefill_32k roofline numbers and is the
 standard TPU adaptation of flash attention in pure JAX.
+
+Causal self-attention over two kernel blocks or more (``flash_applies``)
+runs instead in a Pallas flash kernel (``flash_attention``, jax's splash
+attention): its score tiles stay in VMEM, where the chunked path writes
+each (q-chunk, kv-chunk) tile to HBM. Shorter sequences, non-causal
+attention and tensor-parallel heads keep the chunked path.
 """
 from __future__ import annotations
 
@@ -14,10 +20,25 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
+from jax.sharding import AxisType
 
+from repro import trace
+from repro.kernels import ops
 from repro.models.layers import apply_rope, param
 
 NEG_INF = -1e30
+
+# Query and key rows per flash-kernel block; a sequence takes the kernel from
+# two blocks up. Timed on a TPU v5e at stablelm-3b's shape (PERF.md §6):
+# 1024-row blocks (the forward over 512-key slices) with one fused dq/dkv
+# backward kernel beat 512-row blocks and separate dq and dkv kernels.
+FLASH_BLOCK = 1024
+_FLASH_BLOCKS = splash.BlockSizes(
+    block_q=FLASH_BLOCK, block_kv=FLASH_BLOCK, block_kv_compute=512,
+    block_q_dkv=FLASH_BLOCK, block_kv_dkv=FLASH_BLOCK, block_kv_dkv_compute=FLASH_BLOCK,
+    use_fused_bwd_kernel=True,
+)
 
 
 def init_attention(key, cfg, rec, path, cross: bool = False):
@@ -38,20 +59,22 @@ def init_attention(key, cfg, rec, path, cross: bool = False):
     return p
 
 
-def _qkv(p, x, cfg, positions=None, rope: bool = True):
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if "bq" in p:
-        q = q + p["bq"]
-        k = k + p["bk"]
-        v = v + p["bv"]
+def _qkv(p, x, cfg, positions=None, rope: bool = True, heads_first: bool = False):
+    """q, k, v (B, S, H, hd), or (B, H, S, hd) with ``heads_first``, the
+    flash kernel's layout, straight from the projections."""
+    spec = "bsd,dhk->bhsk" if heads_first else "bsd,dhk->bshk"
+    q = jnp.einsum(spec, x, p["wq"])
+    k = jnp.einsum(spec, x, p["wk"])
+    v = jnp.einsum(spec, x, p["wv"])
+    if "bq" in p:  # (H, hd); over (H, S, hd) in the kernel's layout
+        bq, bk, bv = (p[n][:, None] if heads_first else p[n] for n in ("bq", "bk", "bv"))
+        q, k, v = q + bq, k + bk, v + bv
     if rope and positions is not None:
         from repro.models.layers import rope_angles
 
         cos, sin = rope_angles(positions, cfg.resolved_head_dim, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q = apply_rope(q, cos, sin, heads_first)
+        k = apply_rope(k, cos, sin, heads_first)
     return q, k, v
 
 
@@ -146,26 +169,72 @@ def chunked_attention(q, k, v, *, causal: bool, q_chunk: int, num_kv_heads: int,
     return out.astype(q.dtype)
 
 
-def _repeat_kv(k, v, cfg):
+def flash_applies(s: int, causal: bool) -> bool:
+    """Whether self-attention over ``s`` positions runs in the flash kernel:
+    causal, over two kernel blocks or more, and traced where a Mosaic kernel
+    lowers. Mosaic kernels cannot be partitioned automatically, so every
+    mesh axis has to be manual (the train step's ``shard_map``), or the
+    program has to span one device."""
+    if not causal or s % FLASH_BLOCK or s < 2 * FLASH_BLOCK:
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() == 1
+    manual = [t == AxisType.Manual for t in mesh.axis_types]
+    return all(manual) or (not any(manual) and mesh.size == 1)
+
+
+def flash_attention(q, k, v):
+    """Causal softmax attention in a Pallas flash kernel: q, k, v
+    (B, H, S, hd) -> (B, H, S, hd), S a multiple of ``FLASH_BLOCK``.
+
+    jax's splash attention, one kernel a batch row: the score tile of each
+    (q-block, kv-block) pair lives in VMEM, blocks above the diagonal are
+    skipped, and the backward pass (one fused dq/dkv kernel) recomputes the
+    scores. float32 scores, max, sum and accumulators, as on the chunked
+    path; the forward's PV matmul takes float32 probabilities, and dq is
+    summed over key blocks from bf16 partials, as the chunked path's scan
+    sums it over chunk pairs."""
+    h, s, hd = q.shape[1:]
+    q = q * jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
+    mask = splash.MultiHeadMask([splash.CausalMask((s, s))] * h)
+    kernel = splash.make_splash_mha(mask, block_sizes=_FLASH_BLOCKS, head_shards=1,
+                                    q_seq_shards=1, interpret=ops.interpret())
+    with trace.scope("attn.kernel"):
+        return jax.vmap(kernel)(q, k, v)
+
+
+def _repeat_kv(k, v, cfg, axis: int = 2):
     """Materialize GQA KV to the full head count for train/prefill einsums.
 
     Keeps SPMD sharding propagation trivial (q and k/v share the same H axis
     layout) at the cost of a transient g-times larger KV activation — the
-    standard Megatron-style duplication; decode keeps the grouped form."""
+    standard Megatron-style duplication; decode keeps the grouped form.
+    ``axis`` is the heads axis: 1 in the flash kernel's layout."""
     g = cfg.num_heads // cfg.num_kv_heads
     if g == 1:
         return k, v
-    return jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    return jnp.repeat(k, g, axis=axis), jnp.repeat(v, g, axis=axis)
+
+
+def _self_attention(p, x, cfg, positions, causal: bool = True, rope: bool = True):
+    """x (B, S, d) attending over itself: the output (B, S, d), and k, v
+    (B, S, K, hd) as projected, before the GQA repeat."""
+    if flash_applies(x.shape[1], causal):
+        q, k, v = _qkv(p, x, cfg, positions, rope=rope, heads_first=True)
+        out = flash_attention(q, *_repeat_kv(k, v, cfg, axis=1))
+        return (jnp.einsum("bhsk,hkd->bsd", out, p["wo"]),
+                k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    q, k, v = _qkv(p, x, cfg, positions, rope=rope)
+    out = chunked_attention(
+        q, *_repeat_kv(k, v, cfg), causal=causal, q_chunk=cfg.attn_q_chunk,
+        num_kv_heads=cfg.num_heads, remat_step=cfg.flash_remat,
+    )
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), k, v
 
 
 def attention_train(p, x, cfg, positions, causal: bool = True, rope: bool = True):
-    q, k, v = _qkv(p, x, cfg, positions, rope=rope)
-    k, v = _repeat_kv(k, v, cfg)
-    out = chunked_attention(
-        q, k, v, causal=causal, q_chunk=cfg.attn_q_chunk, num_kv_heads=cfg.num_heads,
-        remat_step=cfg.flash_remat,
-    )
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    return _self_attention(p, x, cfg, positions, causal, rope)[0]
 
 
 class KVCache(NamedTuple):
@@ -180,17 +249,12 @@ def init_kv_cache(batch, max_len, cfg, dtype):
 
 def attention_prefill(p, x, cfg, positions, cache: KVCache):
     """Run full-sequence attention and write k/v into the cache at [0, S)."""
-    q, k, v = _qkv(p, x, cfg, positions)
+    out, k, v = _self_attention(p, x, cfg, positions)
     cache = KVCache(
         k=jax.lax.dynamic_update_slice_in_dim(cache.k, k.astype(cache.k.dtype), 0, axis=1),
         v=jax.lax.dynamic_update_slice_in_dim(cache.v, v.astype(cache.v.dtype), 0, axis=1),
     )
-    k, v = _repeat_kv(k, v, cfg)
-    out = chunked_attention(
-        q, k, v, causal=True, q_chunk=cfg.attn_q_chunk, num_kv_heads=cfg.num_heads,
-        remat_step=cfg.flash_remat,
-    )
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
+    return out, cache
 
 
 def attention_decode(p, x, cfg, cache: KVCache, pos):

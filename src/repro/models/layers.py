@@ -89,16 +89,16 @@ def rope_angles(positions, head_dim, theta):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def apply_rope(x, cos, sin):
-    """x: (B, S, H, hd); cos/sin: (B?, S, hd//2) or (S, hd//2)."""
+def apply_rope(x, cos, sin, heads_first: bool = False):
+    """x: (B, S, H, hd), or (B, H, S, hd) with ``heads_first``; cos/sin:
+    (B?, S, hd//2) or (S, hd//2)."""
     half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
     if cos.ndim == 2:  # (S, half) -> broadcast over batch and heads
-        cos = cos[None, :, None, :]
-        sin = sin[None, :, None, :]
-    else:  # (B, S, half)
-        cos = cos[:, :, None, :]
-        sin = sin[:, :, None, :]
+        cos, sin = cos[None], sin[None]
+    if heads_first:  # (B, S, half) -> (B, 1, S, half)
+        cos, sin = cos[:, None], sin[:, None]
+    else:  # (B, S, half) -> (B, S, 1, half)
+        cos, sin = cos[:, :, None], sin[:, :, None]
     xf = x.astype(jnp.float32)
     x1f, x2f = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1)

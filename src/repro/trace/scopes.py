@@ -14,7 +14,10 @@ Every scope the program opens is in ``SCOPES``, so that tests and the
 benchmark's readers can cite them:
 
 - ``model.embed``: the token embedding (``models/transformer._input_embeds``)
-- ``model.attn``: a block's ln1 norm, attention and its residual add
+- ``model.attn``: a block's ln1 norm, attention and its residual add; within
+  it
+- ``attn.kernel``: the Pallas flash-attention kernel
+  (``models/attention.flash_attention``), forward and backward
 - ``model.mlp``: a block's ln2 norm, MLP (or MoE) and its residual add
 - ``model.head``: the final norm, the head einsum, log-softmax and the loss
 - ``agg``: the whole gradient aggregation (``Aggregator``), barriers and
@@ -30,7 +33,7 @@ from __future__ import annotations
 
 import jax
 
-SCOPES = ("model.embed", "model.attn", "model.mlp", "model.head",
+SCOPES = ("model.embed", "model.attn", "attn.kernel", "model.mlp", "model.head",
           "agg", "agg.encode", "agg.psum", "agg.decode", "optim")
 
 

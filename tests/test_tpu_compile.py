@@ -156,3 +156,23 @@ def test_train_step_compiles_the_same_around_either_backend(one_chip, monkeypatc
     assert sum(fusions["pallas"].values()) > 50
     assert fusions["pallas"] == fusions["jnp"], (
         fusions["pallas"] - fusions["jnp"], fusions["jnp"] - fusions["pallas"])
+
+
+def test_flash_attention_compiles(one_chip, monkeypatch):
+    """The flash-attention kernel at the stablelm-3b cell's shape (one row of
+    4096 tokens, 32 heads of 80, bf16): its forward and its backward (one
+    fused dq/dkv kernel), each a Mosaic call."""
+    from repro.kernels import ops
+    from repro.models import attention
+
+    # this process sees the CPU; the described chip compiles Mosaic kernels
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    qkv = [jax.ShapeDtypeStruct((1, 32, 4096, 80), jnp.bfloat16, sharding=one_chip)] * 3
+
+    def forward_backward(q, k, v):
+        out, vjp = jax.vjp(attention.flash_attention, q, k, v)
+        return out, vjp(out)
+
+    txt = _compiled_text(forward_backward, *qkv)
+    calls = re.findall(r'custom_call_target="tpu_custom_call"', txt)
+    assert len(calls) == 2, len(calls)

@@ -316,8 +316,9 @@ def test_scope_names_only_the_program_scopes():
 
 def test_compiled_train_step_carries_every_scope():
     """A smoke dense train step with fpisa aggregation on a one-device mesh,
-    compiled: its op metadata names every scope of ``trace.SCOPES``, a
-    backward (transpose) op under ``model.attn``, and an all-reduce under
+    over sequences long enough for the flash-attention kernel, compiled:
+    its op metadata names every scope of ``trace.SCOPES``, a backward
+    (transpose) op under ``model.attn``, and an all-reduce under
     ``agg.psum``."""
     from repro.configs import get_smoke_config
     from repro.core.agg import AggConfig
@@ -328,7 +329,7 @@ def test_compiled_train_step_carries_every_scope():
     mesh = make_mesh_for(devices=jax.devices()[:1])
     model, opt_cfg, step = build_step(cfg, mesh, AggConfig(strategy="fpisa", backend="jnp"), 2)
     params, opt = init_state(model, cfg, mesh, opt_cfg)
-    text = step.lower(params, opt, {"tokens": jnp.zeros((2, 16), jnp.int32)}).compile().as_text()
+    text = step.lower(params, opt, {"tokens": jnp.zeros((2, 2048), jnp.int32)}).compile().as_text()
     ops = _op_names(text)
     assert {_innermost(name) for _, name in ops} >= set(trace.SCOPES)
     assert any("transpose(" in name and _innermost(name) == "model.attn" for _, name in ops)
