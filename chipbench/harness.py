@@ -1,10 +1,11 @@
 """The harness: one run of one cell.
 
 Everything that belongs to a configuration, a traffic mix, a cell or a
-per-layer metric is found by its name: ``configs/<config>.json``,
-``traffic/<traffic>.json``, ``workloads/<cell>.json`` and
-``metrics/<metric>.py`` under this directory, and the cell's entries in
-``BENCHMARK.json`` at the root of the checkout. Adding one edits no file here.
+per-layer metric is found by its name: ``configs/<config>.json`` and the
+model it names, ``models/<model>.py``, ``traffic/<traffic>.json``,
+``workloads/<cell>.json`` and ``metrics/<metric>.py`` under this directory,
+and the cell's entries in ``BENCHMARK.json`` at the root of the checkout.
+Adding one edits no file here.
 
 A run: set-up builds the program's step and state from the seed and drives
 its first steps (the warm-up, which compiles or loads every program the window
@@ -28,7 +29,7 @@ import time
 import jax
 import numpy as np
 
-from chipbench import check, reference, trace
+from chipbench import check, models, reference, trace
 from chipbench.corpus import Corpus
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -46,7 +47,7 @@ def _json(path):
 
 def load_cell(name: str, root: str = ROOT) -> dict:
     """The cell ``name``: its BENCHMARK.json entry and metrics, workload,
-    configuration, traffic and the table of peaks, all by name."""
+    configuration and its model, traffic and the table of peaks, all by name."""
     here = os.path.join(root, "chipbench")
     bench = _json(os.path.join(root, "BENCHMARK.json"))
     entry = next((w for w in bench["workloads"] if w["name"] == name), None)
@@ -57,6 +58,7 @@ def load_cell(name: str, root: str = ROOT) -> dict:
         raise ValueError(f"workloads/{name}.json disagrees with BENCHMARK.json")
     cfg_entry = next(c for c in bench["configs"] if c["name"] == entry["config"])
     cfg = _json(os.path.join(root, cfg_entry["file"]))
+    cfg[models.FILE_KEY] = models.path(cfg, os.path.join(here, "models"))
     traffic = _json(os.path.join(here, "traffic", f"{entry['traffic']}.json"))
 
     def applies(m):

@@ -12,39 +12,20 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from chipbench import weights
-from repro.configs import get_config
+from chipbench import models, weights
 from repro.core.agg import AggConfig
 from repro.launch.train import build_step, state_shardings
 from repro.optim import optimizers
 from repro.runtime.elastic import make_mesh_for
 from repro.sharding import rules
 
-# configuration-file key -> ModelConfig field, for every key that shapes the model
-_FIELDS = {
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "num_hidden_layers": "num_layers",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-    "head_dim": "head_dim",
-}
 _OPT_KEYS = ("lr", "b1", "b2", "eps", "weight_decay", "grad_clip", "warmup_steps")
 
 
 def model_config(cfg: dict):
-    """The registry's ModelConfig with every model key of the file applied."""
-    if cfg.get("partial_rotary_factor", 1.0) != 1.0 or cfg["hidden_act"] != "silu":
-        raise ValueError(f"{cfg['name']}: the program has no path for this configuration")
-    fields = {f: cfg[k] for k, f in _FIELDS.items() if k in cfg}
-    dtype = cfg["torch_dtype"]
-    return get_config(cfg["registry"]).with_(
-        **fields, norm_eps=cfg.get("rms_norm_eps", cfg.get("layer_norm_eps")),
-        qkv_bias=bool(cfg.get("use_qkv_bias", cfg.get("qkv_bias", False))),
-        param_dtype=dtype, activation_dtype=dtype, optimizer=cfg["optimizer"]["name"])
+    """The program's ModelConfig for the configuration, as its model maps it
+    (``chipbench.models``)."""
+    return models.of(cfg).program_config(cfg)
 
 
 def _leaf_norms(tree, scale=1.0):

@@ -2,18 +2,15 @@
 AdamW, in float32 at the highest matmul precision, from the configuration file
 alone. It imports nothing of the program.
 
-The model is the configuration as it is run (``departures`` in its file):
-pre-norm decoder blocks with RMSNorm, rotary position embedding (rotate-half
-convention over ``partial_rotary_factor`` of each head), causal softmax
-attention, a SwiGLU MLP, and a tied or untied head; the loss is the mean
-next-token cross-entropy. Parameters are held in bfloat16, as the
+The model's forward pass and loss are those of the module the configuration
+names (``chipbench.models``), every matmul through ``_mm`` here; the loss is
+the mean next-token cross-entropy. Parameters are held in bfloat16, as the
 configuration states, and every update is computed in float32 and rounded back
 to bfloat16 (the repo's AdamW keeps no float32 master copy).
 
-It runs after the program's state is freed, in blocks so that it fits: a
-checkpoint around each layer and each block of query rows, the batch in blocks
-of rows, and AdamW's moments on the host between steps, one leaf at a time on
-the device.
+It runs after the program's state is freed, in blocks so that it fits: the
+batch in blocks of rows, and AdamW's moments on the host between steps, one
+leaf at a time on the device; the model blocks its own forward pass.
 
 ``precision="fp8"`` is the control: every matmul operand rounded to the 3
 mantissa bits of float8_e4m3 and every cotangent reaching a matmul to the 2 of
@@ -32,10 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.weights import dims, flatten, maker, nest
+from chipbench import models
+from chipbench.weights import flatten, maker, nest
 
 HIGHEST = jax.lax.Precision.HIGHEST
-Q_CHUNK = 1024  # query rows per attention block
 
 
 def _round_mantissa(x, bits):
@@ -72,76 +69,11 @@ def _rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
 
 
-def _rope(x, positions, theta, rot):
-    """Rotate the first ``rot`` dims of each head (rotate-half convention)."""
-    half = rot // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None] * inv  # (S, half)
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2, rest = x[..., :half], x[..., half:rot], x[..., rot:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-
-
-def _attention(q, k, v, precision):
-    """Causal softmax attention, one checkpointed block of query rows at a time."""
-    s, hd = q.shape[1], q.shape[-1]
-    g = q.shape[2] // k.shape[2]
-    if g > 1:
-        k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    out = []
-    for start in range(0, s, Q_CHUNK):
-        stop = min(start + Q_CHUNK, s)
-
-        @jax.checkpoint
-        def block(qb, kb, vb, start=start, stop=stop):
-            scores = _mm("bqhd,bkhd->bhqk", qb, kb, precision) / math.sqrt(hd)
-            keep = jnp.arange(start, stop)[:, None] >= jnp.arange(stop)[None, :]
-            scores = jnp.where(keep, scores, -jnp.inf)
-            return _mm("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), vb, precision)
-
-        out.append(block(q[:, start:stop], k[:, :stop], v[:, :stop]))
-    return jnp.concatenate(out, axis=1)
-
-
-def _eps(cfg):
-    return cfg.get("rms_norm_eps", cfg.get("layer_norm_eps"))
-
-
 def nll_sum(params, tokens, cfg, precision="f32", targets=None):
     """Sum over the rows of ``tokens`` of the next-token negative log-likelihood
-    of the first ``targets`` positions (all by default); ``params`` is the
-    float32 tree of ``chipbench.weights``."""
-    s = dims(cfg)
-    eps = _eps(cfg)
-    rot = int(s["hd"] * cfg.get("partial_rotary_factor", 1.0))
-    seq = tokens.shape[1]
-    targets = targets or seq - 1
-    positions = jnp.arange(seq)
-    x = params["embed"]["tok"][tokens]
-
-    @jax.checkpoint
-    def layer(x, lp):
-        a = lp["attn"]
-        h = _rms(x, lp["ln1"]["w"], eps)
-        q = _mm("bsd,dhk->bshk", h, a["wq"], precision)
-        k = _mm("bsd,dhk->bshk", h, a["wk"], precision)
-        v = _mm("bsd,dhk->bshk", h, a["wv"], precision)
-        if "bq" in a:
-            q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-        q = _rope(q, positions, cfg["rope_theta"], rot)
-        k = _rope(k, positions, cfg["rope_theta"], rot)
-        x = x + _mm("bshk,hkd->bsd", _attention(q, k, v, precision), a["wo"], precision)
-        m = lp["mlp"]
-        h = _rms(x, lp["ln2"]["w"], eps)
-        gate = _mm("bsd,df->bsf", h, m["wg"], precision)
-        up = _mm("bsd,df->bsf", h, m["wi"], precision)
-        return x + _mm("bsf,fd->bsd", jax.nn.silu(gate) * up, m["wo"], precision), None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    x = _rms(x[:, :targets], params["final_norm"]["w"], eps)
-    head = params["embed"]["tok"].T if cfg["tie_word_embeddings"] else params["head"]["w"]
-    logp = jax.nn.log_softmax(_mm("bsd,dv->bsv", x, head, precision), axis=-1)
-    return -jnp.sum(jnp.take_along_axis(logp, tokens[:, 1:targets + 1, None], axis=-1))
+    of the first ``targets`` positions (all by default), by the configuration's
+    model; ``params`` is the float32 tree of ``chipbench.weights``."""
+    return models.of(cfg).nll_sum(params, tokens, cfg, precision, targets)
 
 
 @functools.lru_cache(maxsize=None)

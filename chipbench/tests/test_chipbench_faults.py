@@ -3,10 +3,9 @@ correct: a step that hands its state back unchanged, half the batch left out,
 the exchange between chips left out, a loss altered where it is produced, and
 the control (the reference in the program's place at fp8). The reference
 itself in the program's place comes out correct. Each cell's own limits, at
-a size a CPU holds: the faults on a tiny model, the control at the published
-widths with one layer and a 4,096-row vocabulary (below the widths, a model's
-logits are too small for fp8 to move its loss). The harness's look for a chip
-is skipped."""
+sizes a CPU holds, which each configuration's model gives: the faults on its
+``SMALL`` model, the control on its ``WIDE`` one (the published widths at
+little depth). The harness's look for a chip is skipped."""
 import json
 import os
 import shutil
@@ -15,25 +14,23 @@ import time
 import jax
 import pytest
 
-from chipbench import harness, reference
+from chipbench import harness, models, reference
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
-            intermediate_size=128, vocab_size=512, num_hidden_layers=2)
-CELLS = ("stablelm-3b.train.b1x4096", "qwen1.5-0.5b.dp4.b8x512")
-
-
-WIDE = dict(num_hidden_layers=1, vocab_size=4096)
-WIDE_SEQ = {"train.b1x4096": 128, "dp4.b8x512": 64}
+CELLS = ("stablelm-3b.train.b1x4096", "qwen1.5-0.5b.dp4.b8x512", "qwen1.5-0.5b.train.b8x512")
+WIDE_SEQ = {"train.b1x4096": 128, "dp4.b8x512": 64, "train.b8x512": 64}
 
 
 def _root(tmp_path_factory, name, sizes, seq_len):
+    """A copy of the benchmark with each configuration cut to its model's
+    ``sizes`` (``SMALL`` or ``WIDE``) and each traffic mix to ``seq_len``."""
     root = tmp_path_factory.mktemp(name)
     shutil.copytree(os.path.join(ROOT, "chipbench"), root / "chipbench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
     for path in (root / "chipbench" / "configs").iterdir():
-        path.write_text(json.dumps({**json.loads(path.read_text()), **sizes}))
+        cfg = json.loads(path.read_text())
+        path.write_text(json.dumps({**cfg, **getattr(models.of(cfg), sizes)}))
     for path in (root / "chipbench" / "traffic").iterdir():
         traffic = json.loads(path.read_text())
         path.write_text(json.dumps({**traffic, "seq_len": seq_len(traffic["name"])}))
@@ -42,12 +39,12 @@ def _root(tmp_path_factory, name, sizes, seq_len):
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    return _root(tmp_path_factory, "tiny", TINY, lambda _: 64)
+    return _root(tmp_path_factory, "tiny", "SMALL", lambda _: 64)
 
 
 @pytest.fixture(scope="module")
 def wide_root(tmp_path_factory):
-    return _root(tmp_path_factory, "wide", WIDE, WIDE_SEQ.get)
+    return _root(tmp_path_factory, "wide", "WIDE", WIDE_SEQ.get)
 
 
 def _run(root, cell, **kw):

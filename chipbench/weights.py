@@ -1,11 +1,11 @@
 """The weights of a cell, made on the device from the seed.
 
 The benchmark makes the weights itself, so the reference never takes them from
-the program: both start from this tree. Its layout is the repo's transformer
-parameter tree (stacked layers, ``(d, heads, head_dim)`` projections), which
-the harness checks against the program's own before it runs. Every matrix is
-drawn in one jitted call, in bfloat16, straight into the shardings the caller
-gives; the seed enters as data, so every seed runs the same compiled program.
+the program: both start from this tree. Its layout is that of the
+configuration's model (``chipbench.models``), which the harness checks against
+the program's own before it runs. Every matrix is drawn in one jitted call, in
+bfloat16, straight into the shardings the caller gives; the seed enters as
+data, so every seed runs the same compiled program.
 """
 from __future__ import annotations
 
@@ -15,45 +15,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import models
+
 ONES = "ones"
 ZEROS = "zeros"
 
 
-def dims(cfg: dict) -> dict:
-    """The sizes of a configuration file, under short names."""
-    d = cfg["hidden_size"]
-    h = cfg["num_attention_heads"]
-    return {"d": d, "h": h, "k": cfg["num_key_value_heads"], "hd": cfg.get("head_dim", d // h),
-            "ff": cfg["intermediate_size"], "v": cfg["vocab_size"], "L": cfg["num_hidden_layers"]}
-
-
 def leaf_specs(cfg: dict) -> dict:
-    """{path: (shape, init)} of every parameter; init is a std, ONES or ZEROS."""
-    s = dims(cfg)
-    d, h, k, hd, ff, v, L = (s[n] for n in ("d", "h", "k", "hd", "ff", "v", "L"))
-    std = 0.02
-    # output projections scale with the depth of the whole published model
-    out_std = std / math.sqrt(2 * cfg["reduced"].get("num_hidden_layers", [L])[0])
-    specs = {
-        "embed/tok": ((v, d), std),
-        "layers/ln1/w": ((L, d), ONES),
-        "layers/attn/wq": ((L, d, h, hd), std),
-        "layers/attn/wk": ((L, d, k, hd), std),
-        "layers/attn/wv": ((L, d, k, hd), std),
-        "layers/attn/wo": ((L, h, hd, d), out_std),
-        "layers/ln2/w": ((L, d), ONES),
-        "layers/mlp/wi": ((L, d, ff), std),
-        "layers/mlp/wg": ((L, d, ff), std),
-        "layers/mlp/wo": ((L, ff, d), out_std),
-        "final_norm/w": ((d,), ONES),
-    }
-    if cfg.get("use_qkv_bias", cfg.get("qkv_bias", False)):
-        specs.update({"layers/attn/bq": ((L, h, hd), ZEROS),
-                      "layers/attn/bk": ((L, k, hd), ZEROS),
-                      "layers/attn/bv": ((L, k, hd), ZEROS)})
-    if not cfg["tie_word_embeddings"]:
-        specs["head/w"] = ((d, v), std)
-    return specs
+    """{path: (shape, init)} of every parameter, as the configuration's model
+    gives them (``chipbench.models``); init is a std, ONES or ZEROS."""
+    return models.of(cfg).leaf_specs(cfg)
 
 
 def nest(flat: dict) -> dict:
