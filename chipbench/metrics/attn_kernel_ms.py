@@ -23,13 +23,14 @@ def read(ctx):
     paths = scopes.op_paths(ctx)
     if not paths:
         return None
+    table = scopes.layer_table(ctx.cfg)
 
     def in_kernel(op):
         opcode, out_type, path = paths.get(op[0], (None, None, ""))
         if (opcode, out_type) != (op[3], op[4]):
             return False
         return bool(_SCOPE.search(path)) or (
-            op[0].startswith(KERNELS) and scopes.layer_of(op, paths) == "attention_ms")
+            op[0].startswith(KERNELS) and scopes.layer_of(op, paths, table) == "attention_ms")
 
     t = ctx.per_chip_s(lambda ops: sum(op[2] for op in ops if in_kernel(op)))
     return t / ctx.steps * 1e3 if t else None

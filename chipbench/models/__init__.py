@@ -7,8 +7,11 @@ configuration (``FILE_KEY``); a configuration read straight from its file
 finds the module in this directory. Adding a model adds one file here and
 edits none. The module gives:
 
-- ``leaf_specs(cfg)``: {path: (shape, init)} of every parameter, in the
-  repo's parameter layout (``chipbench.weights`` draws them);
+- ``leaf_specs(cfg)``: {path: (shape, init)} or {path: (shape, init, dtype)}
+  of every parameter, in the repo's parameter layout (``chipbench.weights``
+  draws them): init is a std of a normal draw, ``weights.ONES``,
+  ``weights.ZEROS`` or a function of (key, shape) that returns float32, and
+  a leaf without a dtype is held in the configuration's ``torch_dtype``;
 - ``nll_sum(params, tokens, cfg, precision, targets)``: the plain float32
   reference's forward pass and summed next-token loss, every matmul through
   ``chipbench.reference._mm`` so that ``precision="fp8"`` is the control;
@@ -17,7 +20,10 @@ edits none. The module gives:
 - ``program_config(cfg)``: the program's ``ModelConfig`` for the file;
 - ``SMALL`` and ``WIDE``: keys that shrink the file to sizes a CPU holds, a
   tiny model for the planted faults and the published widths at little depth
-  for the fp8 control.
+  for the fp8 control;
+- optionally ``LAYERS``: {metric: (scope, ...)}, the program's scopes of the
+  model's own layers and the per-layer metric each counts under, beside the
+  scopes every model shares (``chipbench.scopes``).
 """
 from __future__ import annotations
 

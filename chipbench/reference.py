@@ -4,9 +4,10 @@ alone. It imports nothing of the program.
 
 The model's forward pass and loss are those of the module the configuration
 names (``chipbench.models``), every matmul through ``_mm`` here; the loss is
-the mean next-token cross-entropy. Parameters are held in bfloat16, as the
-configuration states, and every update is computed in float32 and rounded back
-to bfloat16 (the repo's AdamW keeps no float32 master copy).
+the mean next-token cross-entropy. Each parameter is held in its leaf's dtype
+(``chipbench.weights``: the configuration's ``torch_dtype`` unless the model
+names another), and every update is computed in float32 and rounded back to
+that dtype (the repo's AdamW keeps no float32 master copy).
 
 It runs after the program's state is freed, in blocks so that it fits: the
 batch in blocks of rows, and AdamW's moments on the host between steps, one

@@ -4,12 +4,14 @@ The program names its layers with ``jax.named_scope`` (``repro.trace.SCOPES``).
 The name stack lands in each HLO instruction's ``op_name`` metadata, through
 ``jvp``, ``transpose`` and remat, so a backward op carries the scope of its
 forward op (``transpose(jvp(model.attn))``). An op counts under the innermost
-of the scopes in ``LAYERS`` on its path, and a fusion carries its root's path.
-The readers ``attention_ms``, ``mlp_ms``, ``vocab_ms``, ``agg_ms`` and
-``optimizer_ms`` take the leaf ops of one layer each, and ``unscoped_ms`` those
-under none: together they partition the step's device op time. An
-asynchronous collective counts for its start and done ops, not for its time
-in flight.
+scope on its path of the cell's table (``layer_table``): the scopes of
+``LAYERS``, which every model shares, and those of the ``LAYERS`` of the
+cell's model (``chipbench.models``); a fusion carries its root's path. The
+readers ``attention_ms``, ``mlp_ms``, ``vocab_ms``, ``agg_ms`` and
+``optimizer_ms``, and a reader of each metric a model adds, take the leaf ops
+of one layer each, and ``unscoped_ms`` those under none: together they
+partition the step's device op time. An asynchronous collective counts for its
+start and done ops, not for its time in flight.
 
 The paths come from the HLO of the cell's train step, compiled again from
 its shapes for the chips the run used (``step_hlo``): the persistent compile
@@ -31,7 +33,7 @@ import re
 import jax
 import jax.numpy as jnp
 
-from chipbench import trace
+from chipbench import models, trace
 
 LAYERS = {
     "attention_ms": ("model.attn",),
@@ -48,18 +50,33 @@ _REF = re.compile(r"%([\w.\-]+)")
 _HOPS = 8  # how far an instruction without a path looks for its neighbours' scope
 
 
-def innermost(path: str) -> str | None:
-    """The last component of an ``op_name`` path that is a scope of ``LAYERS``.
-    A transformation wraps a component: ``transpose(jvp(model.attn))``."""
+def layer_table(cfg: dict | None = None) -> dict:
+    """{scope: metric} of a cell: the scopes of ``LAYERS``, and those the
+    ``LAYERS`` of the model that ``cfg`` names add. A model may add scopes to a
+    metric of ``LAYERS`` or name a metric of its own, but not move a scope."""
+    table = dict(_LAYER_OF)
+    own = getattr(models.of(cfg), "LAYERS", {}) if cfg and "model" in cfg else {}
+    for metric, names in own.items():
+        for scope in names:
+            if table.setdefault(scope, metric) != metric:
+                raise ValueError(f"model {cfg['model']!r} moves scope {scope!r} "
+                                 f"from {table[scope]} to {metric}")
+    return table
+
+
+def innermost(path: str, table: dict = _LAYER_OF) -> str | None:
+    """The last component of an ``op_name`` path that is a scope of ``table``
+    (``layer_table``). A transformation wraps a component:
+    ``transpose(jvp(model.attn))``."""
     found = None
     for part in path.split("/"):
         part = _WRAPPER.sub("", part).rstrip(")")
-        if part in _LAYER_OF:
+        if part in table:
             found = part
     return found
 
 
-def hlo_paths(hlo_text: str) -> dict:
+def hlo_paths(hlo_text: str, table: dict = _LAYER_OF) -> dict:
     """{instruction name: (opcode, output type, path)} of an HLO module's text,
     parsed as ``trace.parse_op`` parses a trace event's name.
 
@@ -68,8 +85,9 @@ def hlo_paths(hlo_text: str) -> dict:
     carry none (layout and memory-space copies, prefetches, zero fills), and
     a copy of an argument carries the argument's name. Such an instruction
     takes the path of the nearest instruction that consumes its result and
-    has a scope, passing through others like it; where none does, of the
-    nearest that produces its operands; else the path is empty."""
+    has a scope of ``table``, passing through others like it; where none
+    does, of the nearest that produces its operands; else the path is
+    empty."""
     ops, edges = {}, {"users": collections.defaultdict(list), "operands": {}}
     for line in hlo_text.splitlines():
         line = line.strip().removeprefix("ROOT ")
@@ -89,7 +107,7 @@ def hlo_paths(hlo_text: str) -> dict:
             reached = [n for f in frontier for n in edges[way].get(f, ()) if n in ops and n not in seen]
             seen.update(reached)
             for n in reached:
-                if ops[n][2] is not None and innermost(ops[n][2]):
+                if ops[n][2] is not None and innermost(ops[n][2], table):
                     return ops[n][2]
             frontier = [n for n in reached if ops[n][2] is None]
         return None
@@ -140,23 +158,29 @@ def op_paths(ctx) -> dict | None:
                            if len(op) > 5} or None
         devices = jax.devices()[:ctx.chips]
         if ctx.scope_paths is None and devices[0].platform == "tpu":
-            ctx.scope_paths = hlo_paths(step_hlo(ctx.cell, devices))
+            ctx.scope_paths = hlo_paths(step_hlo(ctx.cell, devices), layer_table(ctx.cfg))
     return ctx.scope_paths
 
 
-def layer_of(op, paths: dict) -> str:
-    """The metric that ``op`` counts under: one of ``LAYERS`` or ``UNSCOPED``."""
+def layer_of(op, paths: dict, table: dict = _LAYER_OF) -> str:
+    """The metric that ``op`` counts under: one of ``table``'s
+    (``layer_table``) or ``UNSCOPED``."""
     opcode, out_type, path = paths.get(op[0], (None, None, ""))
     if (opcode, out_type) != (op[3], op[4]):
         return UNSCOPED
-    return _LAYER_OF.get(innermost(path), UNSCOPED)
+    return table.get(innermost(path, table), UNSCOPED)
 
 
 def layer_ms(ctx, metric: str) -> float | None:
     """Device time per step and chip, in ms, of the leaf ops that count under
-    ``metric``; None where no op of the traced window carries a scope."""
+    ``metric``; None where no op of the traced window carries a scope, or
+    where the cell's table (``layer_table``) has no scope of ``metric``."""
+    table = layer_table(ctx.cfg)
+    if metric != UNSCOPED and metric not in table.values():
+        return None
     paths = op_paths(ctx)
     if not paths or not ctx.per_chip_s(
-            lambda ops: sum(op[2] for op in ops if layer_of(op, paths) != UNSCOPED)):
+            lambda ops: sum(op[2] for op in ops if layer_of(op, paths, table) != UNSCOPED)):
         return None
-    return ctx.per_chip_s(lambda ops: sum(op[2] for op in ops if layer_of(op, paths) == metric)) / ctx.steps * 1e3
+    return ctx.per_chip_s(lambda ops: sum(op[2] for op in ops
+                                          if layer_of(op, paths, table) == metric)) / ctx.steps * 1e3

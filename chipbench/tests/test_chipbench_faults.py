@@ -5,7 +5,9 @@ the control (the reference in the program's place at fp8). The reference
 itself in the program's place comes out correct. Each cell's own limits, at
 sizes a CPU holds, which each configuration's model gives: the faults on its
 ``SMALL`` model, the control on its ``WIDE`` one (the published widths at
-little depth). The harness's look for a chip is skipped."""
+little depth). Every cell of ``workloads/`` is covered, the exchange left out
+in each that spans more than one chip. The harness's look for a chip is
+skipped."""
 import json
 import os
 import shutil
@@ -17,8 +19,15 @@ import pytest
 from chipbench import harness, models, reference
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-CELLS = ("stablelm-3b.train.b1x4096", "qwen1.5-0.5b.dp4.b8x512", "qwen1.5-0.5b.train.b8x512")
-WIDE_SEQ = {"train.b1x4096": 128, "dp4.b8x512": 64, "train.b8x512": 64}
+WORKLOADS = os.path.join(ROOT, "chipbench", "workloads")
+CELLS = {name[:-len(".json")]: harness._json(os.path.join(WORKLOADS, name))
+         for name in sorted(os.listdir(WORKLOADS)) if name.endswith(".json")}
+
+
+def _wide_seq(traffic):
+    """The control's row length: a 32nd of the mix's, and at least 64 tokens
+    (128 for 4096-token rows, 64 for 512-token ones)."""
+    return max(64, traffic["seq_len"] // 32)
 
 
 def _root(tmp_path_factory, name, sizes, seq_len):
@@ -33,7 +42,7 @@ def _root(tmp_path_factory, name, sizes, seq_len):
         path.write_text(json.dumps({**cfg, **getattr(models.of(cfg), sizes)}))
     for path in (root / "chipbench" / "traffic").iterdir():
         traffic = json.loads(path.read_text())
-        path.write_text(json.dumps({**traffic, "seq_len": seq_len(traffic["name"])}))
+        path.write_text(json.dumps({**traffic, "seq_len": seq_len(traffic)}))
     return str(root)
 
 
@@ -44,7 +53,7 @@ def tiny_root(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def wide_root(tmp_path_factory):
-    return _root(tmp_path_factory, "wide", "WIDE", WIDE_SEQ.get)
+    return _root(tmp_path_factory, "wide", "WIDE", _wide_seq)
 
 
 def _run(root, cell, **kw):
@@ -71,7 +80,7 @@ def test_reference_in_the_programs_place_is_correct(tiny_root, cell):
 
 @pytest.mark.parametrize("cell, fault", [
     (cell, fault) for cell in CELLS for fault in ("frozen", "half", "loss_off")
-] + [("qwen1.5-0.5b.dp4.b8x512", "one_replica")])
+] + [(cell, "one_replica") for cell, workload in CELLS.items() if workload["chips"] > 1])
 def test_fault_is_not_correct(tiny_root, cell, fault):
     result = _run(tiny_root, cell, variant=fault)
     assert not result["correct"] and _over_a_limit(result), result["checks"]

@@ -3,8 +3,9 @@
 The benchmark makes the weights itself, so the reference never takes them from
 the program: both start from this tree. Its layout is that of the
 configuration's model (``chipbench.models``), which the harness checks against
-the program's own before it runs. Every matrix is drawn in one jitted call, in
-bfloat16, straight into the shardings the caller gives; the seed enters as
+the program's own before it runs. Every leaf is drawn in one jitted call, in
+its own dtype (the configuration's ``torch_dtype`` unless its spec names
+another), straight into the shardings the caller gives; the seed enters as
 data, so every seed runs the same compiled program.
 """
 from __future__ import annotations
@@ -22,9 +23,13 @@ ZEROS = "zeros"
 
 
 def leaf_specs(cfg: dict) -> dict:
-    """{path: (shape, init)} of every parameter, as the configuration's model
-    gives them (``chipbench.models``); init is a std, ONES or ZEROS."""
-    return models.of(cfg).leaf_specs(cfg)
+    """{path: (shape, init, dtype)} of every parameter, as the configuration's
+    model gives them (``chipbench.models``): init is a std, ONES, ZEROS or a
+    function of (key, shape) that returns float32; a spec without a dtype
+    takes the configuration's ``torch_dtype``."""
+    default = cfg["torch_dtype"]
+    return {path: (spec[0], spec[1], spec[2] if len(spec) > 2 else default)
+            for path, spec in models.of(cfg).leaf_specs(cfg).items()}
 
 
 def nest(flat: dict) -> dict:
@@ -56,22 +61,24 @@ def seed_key(seed: int) -> np.ndarray:
     return np.random.SeedSequence(seed).generate_state(2, dtype=np.uint32)
 
 
-def maker(cfg: dict, shardings=None, dtype=jnp.bfloat16):
+def maker(cfg: dict, shardings=None):
     """A function of the seed that draws the parameter tree of ``cfg`` on the
     device in one jitted call; ``shardings`` is a tree like the parameters, or
-    one sharding for all of them."""
+    one sharding for all of them. Leaf ``i`` of the sorted paths draws from
+    the seed's key folded with ``i``."""
     specs = sorted(leaf_specs(cfg).items())
 
     def draw(key):
         flat = {}
-        for i, (path, (shape, init)) in enumerate(specs):
+        for i, (path, (shape, init, dtype)) in enumerate(specs):
             if init == ONES:
                 flat[path] = jnp.ones(shape, dtype)
             elif init == ZEROS:
                 flat[path] = jnp.zeros(shape, dtype)
             else:
                 k = jax.random.fold_in(key, i)
-                flat[path] = (jax.random.normal(k, shape, jnp.float32) * init).astype(dtype)
+                x = init(k, shape) if callable(init) else jax.random.normal(k, shape, jnp.float32) * init
+                flat[path] = x.astype(dtype)
         return nest(flat)
 
     jitted = jax.jit(draw, out_shardings=shardings)
@@ -80,4 +87,4 @@ def maker(cfg: dict, shardings=None, dtype=jnp.bfloat16):
 
 def element_counts(cfg: dict) -> dict:
     """{path: number of elements} of every parameter."""
-    return {p: math.prod(shape) for p, (shape, _) in leaf_specs(cfg).items()}
+    return {p: math.prod(shape) for p, (shape, _, _) in leaf_specs(cfg).items()}
